@@ -8,6 +8,7 @@ import argparse
 import sys
 
 from benchmarks import engine_bench, figures, kernels_bench
+from repro import compile_cache
 
 SUITES = {
     "fig1": figures.fig1_rastrigin_dimension_sweep,
@@ -40,4 +41,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

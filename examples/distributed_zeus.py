@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (
     BFGSOptions,
     MeanFieldPSOOptions,
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -14,6 +14,7 @@ jax.config.update("jax_enable_x64", True)  # the paper fits in double precision
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import BFGSOptions, PSOOptions, ZeusOptions, zeus
 from repro.core.objectives import (
     dijet_rate,
@@ -62,4 +63,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

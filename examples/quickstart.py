@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (
     BFGSOptions,
     MeanFieldPSOOptions,
@@ -88,4 +89,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

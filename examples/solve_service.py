@@ -11,6 +11,7 @@ same pool width): traffic never changes anyone's answer.
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import CONVERGED, BFGSOptions, ZeusOptions
 from repro.serve.service import (
     ProblemRegistry,
@@ -64,4 +65,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -19,6 +19,7 @@ import jax.flatten_util
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config, reduce_config
 from repro.core import BFGSOptions, LBFGSOptions, batched_lbfgs
 from repro.data.pipeline import DataConfig, make_batch
@@ -83,6 +84,7 @@ if __name__ == "__main__":
     ap.add_argument("--optimizer", default="adamw",
                     choices=["adamw", "zeus-lbfgs"])
     args = ap.parse_args()
+    compile_cache.enable()
     if args.optimizer == "adamw":
         adamw_mode(args.steps)
     else:

@@ -43,29 +43,11 @@ from repro.core.zeus import (_RETRY_FOLD, PHASE1_STRATEGIES, ZeusOptions,
                              phase1_particles, solve_phase2, uniform_starts)
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map(check_vma=False) where available (jax >= 0.7), else the
-    experimental namespace with its older check_rep spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
-def _axis_size(name: str) -> jnp.ndarray:
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.6
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)  # constant-folded under shard_map
-
-
 def _axis_index_flat(axis_names: Tuple[str, ...]) -> jnp.ndarray:
     """Flat linear device index across the listed mesh axes."""
     idx = jnp.zeros((), jnp.int32)
     for name in axis_names:
-        idx = idx * _axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 
@@ -280,11 +262,12 @@ def distributed_zeus(
         n_local=n_local,
     )
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         lambda key: local(key),
         mesh=mesh,
         in_specs=(P(),),
         out_specs=out_specs,
+        check_vma=False,
     )
 
     # ------------------------------------------------------------------
@@ -531,16 +514,16 @@ def distributed_zeus(
             jax.ShapeDtypeStruct((n_local, dim), dtype))
         carry_specs = _carry_specs(
             jax.tree.map(lambda l: l, probe), lambda s: s)
-        init_sharded = shard_map_compat(
+        init_sharded = jax.shard_map(
             init_shard, mesh=mesh, in_specs=(P(),),
-            out_specs=(carry_specs, P()))
+            out_specs=(carry_specs, P()), check_vma=False)
         init_jit = jax.jit(init_sharded)
-        seg_jit = jax.jit(shard_map_compat(
+        seg_jit = jax.jit(jax.shard_map(
             seg_shard, mesh=mesh, in_specs=(carry_specs, P()),
-            out_specs=carry_specs))
-        fin_jit = jax.jit(shard_map_compat(
+            out_specs=carry_specs, check_vma=False))
+        fin_jit = jax.jit(jax.shard_map(
             fin_shard, mesh=mesh, in_specs=(carry_specs,),
-            out_specs=(P(), P(), res_specs)))
+            out_specs=(P(), P(), res_specs), check_vma=False))
 
     def run(key: jnp.ndarray,
             resume_from: Optional[str] = None) -> ZeusResult:

@@ -1340,9 +1340,13 @@ def run_multistart(
             if reason is None:
                 step_impl = megakernel_lanes_step
             else:
+                from repro.kernels import ops as kernel_ops
+
                 warnings.warn(
-                    f"sweep_mode='megakernel': {reason}; running the staged "
-                    "batched path instead (bit-identical results)",
+                    f"sweep_mode='megakernel' (D={D}, padded Dp="
+                    f"{kernel_ops._padded_dim(D)}, cap "
+                    f"{kernel_ops.MEGAKERNEL_MAX_DIM}): {reason}; running "
+                    "the staged batched path instead (bit-identical results)",
                     RuntimeWarning, stacklevel=2,
                 )
         init_chunk = lambda X: batch_lanes_init(bobj, bstrategy, X, opts.theta)

@@ -17,7 +17,8 @@ def direction_body(H, g):
     Batched matvec on the MXU (contract last dim of H with g per lane).
     Shared by the standalone kernel below and the sweep megakernel."""
     p = jax.lax.dot_general(
-        H, g, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        H, g, (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # (TB, D)
     return -p
 

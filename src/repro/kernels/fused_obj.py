@@ -105,14 +105,17 @@ def objective_body(name: str, dim: int):
     return OBJECTIVE_BODIES[name](dim)
 
 
+# f leaves the kernels as an (Np, 1) column blocked (tn, 1): Mosaic keeps
+# the row reduction's result in that layout, and a 1-D (tn,) block of an
+# (Np,) array is refused for every Np > tn (XLA tiles the array T(1024)).
 def _value_kernel(body, x_ref, f_ref):
     f, _ = body(x_ref[...])
-    f_ref[...] = f.astype(f_ref.dtype)
+    f_ref[...] = f[:, None].astype(f_ref.dtype)
 
 
 def _value_grad_kernel(body, x_ref, f_ref, g_ref):
     f, g = body(x_ref[...], with_grad=True)
-    f_ref[...] = f.astype(f_ref.dtype)
+    f_ref[...] = f[:, None].astype(f_ref.dtype)
     g_ref[...] = g.astype(g_ref.dtype)
 
 
@@ -130,11 +133,11 @@ def fused_value_pallas(name: str, x: jnp.ndarray, *, dim: int = None,
         functools.partial(_value_kernel, body),
         grid=(Np // tn,),
         in_specs=[pl.BlockSpec((tn, D), lambda n: (n, 0))],
-        out_specs=pl.BlockSpec((tn,), lambda n: (n,)),
-        out_shape=jax.ShapeDtypeStruct((Np,), x.dtype),
+        out_specs=pl.BlockSpec((tn, 1), lambda n: (n, 0)),
+        out_shape=jax.ShapeDtypeStruct((Np, 1), x.dtype),
         interpret=interpret,
     )(x)
-    return f[:N]
+    return f[:N, 0]
 
 
 def fused_value_grad_pallas(name: str, x: jnp.ndarray, *, dim: int = None,
@@ -156,13 +159,13 @@ def fused_value_grad_pallas(name: str, x: jnp.ndarray, *, dim: int = None,
         grid=(Np // tn,),
         in_specs=[pl.BlockSpec((tn, D), lambda n: (n, 0))],
         out_specs=[
-            pl.BlockSpec((tn,), lambda n: (n,)),
+            pl.BlockSpec((tn, 1), lambda n: (n, 0)),
             pl.BlockSpec((tn, D), lambda n: (n, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np,), x.dtype),
+            jax.ShapeDtypeStruct((Np, 1), x.dtype),
             jax.ShapeDtypeStruct((Np, D), x.dtype),
         ],
         interpret=interpret,
     )(x)
-    return f[:N], g[:N]
+    return f[:N, 0], g[:N]

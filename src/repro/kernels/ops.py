@@ -49,7 +49,16 @@ def pallas_enabled() -> bool:
 
 
 def _interpret() -> bool:
-    return not _on_tpu()
+    """Compile the kernels on a TPU; interpret them on the CPU backend, the
+    test seam. Any other backend has no Pallas TPU lowering and is refused,
+    so a kernel never runs interpreted where a device was meant."""
+    backend = jax.default_backend()
+    if backend in ("tpu", "cpu"):
+        return backend == "cpu"
+    raise RuntimeError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU; "
+        f"the default backend is {backend!r}. Set REPRO_DISABLE_PALLAS=1 "
+        f"to use the jnp references there.")
 
 
 @contextlib.contextmanager
@@ -249,10 +258,12 @@ def fused_value(name: str, x: jnp.ndarray):
 
 
 # -- sweep megakernel ---------------------------------------------------------
-# Hard VMEM cap on the PADDED lane dim for the fused sweep kernels: the
-# per-grid-step working set is dominated by H in + H out + the rank-1
-# update temporaries (see kernels/sweep_megakernel.py docstring) — the same
-# envelope the guarded-update kernel already compiles in at Dp = 1024.
+# Cap on the PADDED lane dim for the fused sweep kernels: the per-grid-step
+# working set is dominated by the double-buffered H in + H out blocks and
+# the rank-1 update temporaries (kernels/sweep_megakernel.py docstring).
+# The v5e compiler refuses Dp = 1024 at its default 16 MiB scoped-VMEM
+# limit and accepts it with the 32 MiB that bfgs_update.vmem_params asks
+# for; Dp = 128 and 512 fit the default (tests/test_tpu_compile.py).
 MEGAKERNEL_MAX_DIM = 1024
 
 

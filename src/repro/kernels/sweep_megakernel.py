@@ -54,11 +54,12 @@ into full-ladder rows. So the short-ladder megakernel path reuses
 construction) and fuses everything after the accept — value+grad, guard,
 H', p' — into the commit kernel (launch #2).
 
-VMEM budget per grid step: H in + H out is 2·Dp²·4 B and the three rank-1
-update terms cost up to ~2 more Dp² temporaries before fusion; trials add
+VMEM budget per grid step: H in + H out, each double-buffered by the
+pipeline, is 4·Dp²·4 B, plus the rank-1 update temporaries; trials add
 K·Dp·4 B and the vectors ~8·Dp·4 B. At the ops.MEGAKERNEL_MAX_DIM = 1024
-cap that is ≈16 MB worst-case pre-fusion — the same envelope the existing
-guarded-update kernel already compiles in — and ≈4.2 MB at D = 256.
+cap the blocks alone fill the v5e compiler's default 16 MiB scoped-VMEM
+limit, so both kernels take bfgs_update.vmem_params (a 32 MiB limit at
+Dp = 1024, of the chip's 128 MiB VMEM) exactly like the guarded update.
 Oversized D (and non-fused objectives, and rosenbrock at D not a multiple
 of 128, where zero padding is inexact) are routed back to the staged path
 by `engine.megakernel_unsupported_reason` before this module is reached.
@@ -79,23 +80,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bfgs_update import update_direction_body
+from repro.kernels.bfgs_update import (_lane_rows, update_direction_body,
+                                       vmem_params)
 from repro.kernels.fused_obj import objective_body
 
 _CURV_EPS = 1e-10  # engine._CURV_EPS; kept literal to avoid a core import
 
 
-def _seam(x):
+def _barrier(x):
     """A staged-launch seam: barrier so consumers can't re-fuse across it.
 
     Placed where the staged program materializes an array at a pallas_call
     boundary (trial tensor in, ladder values out, x' in, value+grad out,
     (ρ, δx, δg) in). Elementwise-identity, so it never changes values —
-    only prevents ULP-flipping recontraction across the seam."""
+    only prevents ULP-flipping recontraction across the seam. Interpret
+    mode only: there XLA fuses the kernel body like any other program.
+    Mosaic has no lowering for the barrier, and the compiled kernel keeps
+    the body's op order without one."""
     return jax.lax.optimization_barrier(x)
 
 
-def _commit_tail(body, d, x, p, g, H, act, alpha):
+def _seam_fn(interpret):
+    return _barrier if interpret else (lambda x: x)
+
+
+def _commit_tail(body, _seam, d, x, p, g, H, act, alpha):
     """Stage 4, shared by both kernels: step, value+grad, guard, H', p'.
 
     All inputs are one lane's (Dp,)/(Dp, Dp) rows; `d` is the true dim."""
@@ -119,30 +128,30 @@ def _commit_tail(body, d, x, p, g, H, act, alpha):
     return x_new, f_new, g_new, h_new, p_new
 
 
-def _full_sweep_kernel(body, d, exhaust_alpha, K,
+def _full_sweep_kernel(body, _seam, d, exhaust_alpha, K,
                        x_ref, p_ref, g_ref, h_ref, act_ref, rhs_ref,
                        al_ref,
                        xo_ref, fo_ref, go_ref, ho_ref, po_ref,
                        ao_ref, ro_ref):
-    """Grid step: ONE lane, all four stages. Blocks: x/p/g (1, Dp),
-    H (1, Dp, Dp), act (1,) int32, rhs (K, 1) barriered thresholds,
-    al (K,) the host ladder constants (an input because pallas kernels
+    """Grid step: ONE lane, all four stages. Blocks: x/p/g (1, 1, Dp),
+    H (1, Dp, Dp), act (1, 1, 1) int32, rhs (1, K, 1) barriered thresholds,
+    al (K, 1) the host ladder constants (an input because pallas kernels
     can't close over array constants — values still host-computed by
     linesearch.ladder_alphas)."""
-    x = x_ref[0]
-    p = p_ref[0]
-    act = act_ref[0] != 0
+    x = x_ref[0, 0]
+    p = p_ref[0, 0]
+    act = act_ref[0, 0, 0] != 0
 
     # stages 1–2: the K-rung trial fan and its values, one VMEM pass
-    al = al_ref[...]  # (K,) ladder constants
-    trials = _seam(x[None, :] + al[:, None] * p[None, :])  # (K, Dp)
-    F = _seam(body(trials)[0])  # (K,)
+    al = al_ref[...]  # (K, 1) ladder constants, one rung per row
+    trials = _seam(x[None, :] + al * p[None, :])  # (K, Dp)
+    F = _seam(body(trials)[0])[:, None]  # (K, 1)
 
     # stage 3: first accepted rung. rung = min over accepted rung indices
     # (== the staged argmax-of-first-True when any accept, K when none —
     # exactly the staged exhaustion encoding).
-    ok = F <= rhs_ref[:, 0]
-    kio = jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)[:, 0]
+    ok = F <= rhs_ref[0]
+    kio = jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)
     rung = jnp.min(jnp.where(ok, kio, K)).astype(jnp.int32)
     # α by one-hot sum: the single selected ladder constant survives, every
     # other term is literally 0.0 — a selection, not an arithmetic blend.
@@ -151,38 +160,62 @@ def _full_sweep_kernel(body, d, exhaust_alpha, K,
 
     # stage 4: commit + guarded H-update + next direction
     x_new, f_new, g_new, h_new, p_new = _commit_tail(
-        body, d, x, p, g_ref[0], h_ref[0], act, alpha)
+        body, _seam, d, x, p, g_ref[0, 0], h_ref[0], act, alpha)
+    _store_lane(xo_ref, fo_ref, go_ref, ho_ref, po_ref,
+                x_new, f_new, g_new, h_new, p_new)
+    _store_scalar(ao_ref, alpha)
+    _store_scalar(ro_ref, rung)
 
-    xo_ref[0] = x_new.astype(xo_ref.dtype)
-    fo_ref[0] = f_new.astype(fo_ref.dtype)
-    go_ref[0] = g_new.astype(go_ref.dtype)
+
+def _store_scalar(ref, v):
+    ref[0] = jnp.full((1, 1), v, ref.dtype)
+
+
+def _store_lane(xo_ref, fo_ref, go_ref, ho_ref, po_ref,
+                x_new, f_new, g_new, h_new, p_new):
+    xo_ref[0, 0] = x_new.astype(xo_ref.dtype)
+    _store_scalar(fo_ref, f_new)
+    go_ref[0, 0] = g_new.astype(go_ref.dtype)
     ho_ref[0] = h_new.astype(ho_ref.dtype)
-    po_ref[0] = p_new.astype(po_ref.dtype)
-    ao_ref[0] = alpha.astype(ao_ref.dtype)
-    ro_ref[0] = rung
+    po_ref[0, 0] = p_new.astype(po_ref.dtype)
 
 
-def _commit_kernel(body, d,
+def _commit_kernel(body, _seam, d,
                    x_ref, p_ref, g_ref, h_ref, act_ref, alpha_ref,
                    xo_ref, fo_ref, go_ref, ho_ref, po_ref):
     """Short-ladder commit: stage 4 only, α decided by the staged adaptive
     ladder (launch #1). One lane per grid step, same blocks as above."""
-    x_new, f_new, g_new, h_new, p_new = _commit_tail(
-        body, d, x_ref[0], p_ref[0], g_ref[0], h_ref[0],
-        act_ref[0] != 0, alpha_ref[0])
-    xo_ref[0] = x_new.astype(xo_ref.dtype)
-    fo_ref[0] = f_new.astype(fo_ref.dtype)
-    go_ref[0] = g_new.astype(go_ref.dtype)
-    ho_ref[0] = h_new.astype(ho_ref.dtype)
-    po_ref[0] = p_new.astype(po_ref.dtype)
+    outs = _commit_tail(
+        body, _seam, d, x_ref[0, 0], p_ref[0, 0], g_ref[0, 0], h_ref[0],
+        act_ref[0, 0, 0] != 0, alpha_ref[0, 0, 0])
+    _store_lane(xo_ref, fo_ref, go_ref, ho_ref, po_ref, *outs)
 
 
-def _lane_specs(B, D, K=None):
-    """(in_specs head, out_specs head) shared by both kernels."""
-    vec = pl.BlockSpec((1, D), lambda b: (b, 0))
+def _lane_specs(D):
+    """One lane per grid step: per-lane vectors (B, D) are viewed as
+    (B, 1, D) and scalars (B,) as (B, 1, 1), so every block's last two dims
+    equal the array's — the only legal Mosaic layout for a one-row block."""
+    vec = pl.BlockSpec((1, 1, D), lambda b: (b, 0, 0))
     mat = pl.BlockSpec((1, D, D), lambda b: (b, 0, 0))
-    scl = pl.BlockSpec((1,), lambda b: (b,))
+    scl = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0))
     return vec, mat, scl
+
+
+def _lane_shapes(B, D, dtype):
+    """out_shape for (x', f', g', H', p') in the lane layout above."""
+    return [
+        jax.ShapeDtypeStruct((B, 1, D), dtype),
+        jax.ShapeDtypeStruct((B, 1, 1), dtype),
+        jax.ShapeDtypeStruct((B, 1, D), dtype),
+        jax.ShapeDtypeStruct((B, D, D), dtype),
+        jax.ShapeDtypeStruct((B, 1, D), dtype),
+    ]
+
+
+def _unlane(B, D, x, f, g, H, p, *scalars):
+    """Back from the lane layout: (B, D) vectors, (B,) scalars."""
+    return (x.reshape(B, D), f.reshape(B), g.reshape(B, D), H,
+            p.reshape(B, D)) + tuple(s.reshape(B) for s in scalars)
 
 
 def sweep_megakernel_full_pallas(name, X, P, G, H, active, rhs, alphas_np,
@@ -199,27 +232,25 @@ def sweep_megakernel_full_pallas(name, X, P, G, H, active, rhs, alphas_np,
     body = objective_body(name, d)
     npdt = alphas_np.dtype.type
     exhaust_alpha = npdt(alphas_np[-1] * npdt(shrink))  # staged alphas[-1]·shrink
-    vec, mat, scl = _lane_specs(B, D)
+    vec, mat, scl = _lane_specs(D)
     kernel = functools.partial(
-        _full_sweep_kernel, body, d, exhaust_alpha, K)
-    return pl.pallas_call(
+        _full_sweep_kernel, body, _seam_fn(interpret), d, exhaust_alpha, K)
+    outs = pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[vec, vec, vec, mat, scl,
-                  pl.BlockSpec((K, 1), lambda b: (0, b)),
-                  pl.BlockSpec((K,), lambda b: (0,))],
+                  pl.BlockSpec((1, K, 1), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((K, 1), lambda b: (0, 0))],
         out_specs=[vec, scl, vec, mat, vec, scl, scl],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, D), X.dtype),
-            jax.ShapeDtypeStruct((B,), X.dtype),
-            jax.ShapeDtypeStruct((B, D), X.dtype),
-            jax.ShapeDtypeStruct((B, D, D), H.dtype),
-            jax.ShapeDtypeStruct((B, D), X.dtype),
-            jax.ShapeDtypeStruct((B,), X.dtype),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
+        out_shape=_lane_shapes(B, D, X.dtype) + [
+            jax.ShapeDtypeStruct((B, 1, 1), X.dtype),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
+        compiler_params=vmem_params(D),
         interpret=interpret,
-    )(X, P, G, H, active.astype(jnp.int32), rhs, jnp.asarray(alphas_np))
+    )(*_lane_rows(X, P, G), H, *_lane_rows(active.astype(jnp.int32)),
+      rhs.T[:, :, None], jnp.asarray(alphas_np)[:, None])
+    return _unlane(B, D, *outs)
 
 
 def sweep_megakernel_commit_pallas(name, X, P, G, H, active, alpha,
@@ -230,19 +261,15 @@ def sweep_megakernel_commit_pallas(name, X, P, G, H, active, alpha,
     B, D = X.shape
     d = dim if dim is not None else D
     body = objective_body(name, d)
-    vec, mat, scl = _lane_specs(B, D)
-    kernel = functools.partial(_commit_kernel, body, d)
-    return pl.pallas_call(
+    vec, mat, scl = _lane_specs(D)
+    kernel = functools.partial(_commit_kernel, body, _seam_fn(interpret), d)
+    outs = pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[vec, vec, vec, mat, scl, scl],
         out_specs=[vec, scl, vec, mat, vec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, D), X.dtype),
-            jax.ShapeDtypeStruct((B,), X.dtype),
-            jax.ShapeDtypeStruct((B, D), X.dtype),
-            jax.ShapeDtypeStruct((B, D, D), H.dtype),
-            jax.ShapeDtypeStruct((B, D), X.dtype),
-        ],
+        out_shape=_lane_shapes(B, D, X.dtype),
+        compiler_params=vmem_params(D),
         interpret=interpret,
-    )(X, P, G, H, active.astype(jnp.int32), alpha)
+    )(*_lane_rows(X, P, G), H, *_lane_rows(active.astype(jnp.int32), alpha))
+    return _unlane(B, D, *outs)

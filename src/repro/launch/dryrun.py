@@ -144,8 +144,6 @@ def analyze_cell(arch: str, shape_name: str, mesh_kind: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x wraps it in a list
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     # trip-count-aware analysis of the partitioned module (XLA's own
     # cost_analysis counts while bodies once — see hlo_analysis.py)

@@ -9,7 +9,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.sharding import make_mesh_compat
+from repro.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -17,14 +17,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
     """Whatever devices this host actually has — smoke tests / examples."""
     n = len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return make_mesh_compat((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def mesh_device_count(mesh: Mesh) -> int:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro import compile_cache
 from repro.core import BFGSOptions, ZeusOptions
 from repro.serve.service import ProblemRegistry, SolveRequest, SolveService
 
@@ -100,4 +101,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -16,14 +16,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """jax.make_mesh across jax versions: newer jax wants explicit Auto
-    axis_types; 0.4.x has no axis_types kwarg (everything is Auto)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """jax.make_mesh with Auto axis types. jax defaults to Explicit axes,
+    and the shardings here rely on the compiler propagating them."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 # rule table: logical axis -> tuple of candidate mesh axes (joint sharding)
 DEFAULT_RULES: dict[str, Tuple[str, ...]] = {

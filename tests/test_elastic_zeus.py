@@ -10,7 +10,7 @@ from repro.core import BFGSOptions, PSOOptions, batched_bfgs
 from repro.core.objectives import get_objective
 from repro.core.pso import run_pso
 from repro.launch.faults import reseed_lost_lanes
-from repro.sharding import make_mesh_compat
+from repro.sharding import make_mesh
 
 KEY = jax.random.key(7)
 
@@ -53,7 +53,7 @@ def test_trainstate_cross_mesh_restore_values(tmp_path):
     state = init_train_state(model, KEY, TrainConfig())
     ckpt.save(str(tmp_path), step=3, tree=state)
 
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     out = ckpt.restore(str(tmp_path), state, shardings=sh)
     a = jax.tree.leaves(state.params)[0]

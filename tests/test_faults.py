@@ -504,10 +504,10 @@ _DIST_PREEMPT = """
     from repro.core.distributed import distributed_zeus
     from repro.core.objectives import rosenbrock
     from repro.launch.faults import FaultPlan, Preempted
-    from repro.sharding import make_mesh_compat
+    from repro.sharding import make_mesh
 
     CK = {ck!r}
-    mesh = make_mesh_compat((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
     base = dict(use_pso=False, pso=PSOOptions(n_particles=16, iter_pso=0),
                 bfgs=BFGSOptions(iter_bfgs=40, theta=1e-4, required_c=16),
                 sweep_mode="batched", lane_chunk=4, repack_every=2)
@@ -541,12 +541,12 @@ _DIST_RESUME = """
     from repro.core import BFGSOptions, PSOOptions, ZeusOptions
     from repro.core.distributed import distributed_zeus
     from repro.core.objectives import rosenbrock
-    from repro.sharding import make_mesh_compat
+    from repro.sharding import make_mesh
 
     CK = {ck!r}
     DEV = {devices}
     EXACT = {exact}
-    mesh = make_mesh_compat((DEV,), ("data",))
+    mesh = make_mesh((DEV,), ("data",))
     base = dict(use_pso=False, pso=PSOOptions(n_particles=16, iter_pso=0),
                 bfgs=BFGSOptions(iter_bfgs=40, theta=1e-4, required_c=16),
                 sweep_mode="batched", lane_chunk=4, repack_every=2)

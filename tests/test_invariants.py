@@ -8,7 +8,7 @@ from _hypothesis_compat import given, settings, st
 from repro.core.bfgs import hessian_update_fast, hessian_update_reference
 from repro.core.linesearch import armijo_backtracking
 from repro.core.objectives import rastrigin, rosenbrock, sphere
-from repro.sharding import logical_to_spec, make_mesh_compat
+from repro.sharding import logical_to_spec, make_mesh
 
 _dims = st.integers(2, 12)
 _seeds = st.integers(0, 2**31 - 1)
@@ -71,7 +71,7 @@ def test_secant_equation(dim, seed):
 @given(st.integers(0, 2**31 - 1), st.integers(1, 4), st.integers(1, 4))
 def test_sharding_spec_never_reuses_mesh_axes(seed, d1, d2):
     """Invariant: one mesh axis shards at most one dim of any array."""
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = np.random.default_rng(seed)
     names = ["batch", "heads", "mlp", "fsdp", "expert", "vocab", None,
              "embed", "kv_heads", "expert_mlp"]

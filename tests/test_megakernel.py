@@ -179,7 +179,8 @@ class TestMegakernelFallback:
         from repro.kernels import ops as kernel_ops
         monkeypatch.setattr(kernel_ops, "MEGAKERNEL_MAX_DIM", 128)
         obj, x0 = _starts("rastrigin", 6, 130, seed=3)  # pads to 256 > 128
-        self._expect_fallback(obj.fn, x0, match="VMEM", iter_bfgs=4)
+        self._expect_fallback(obj.fn, x0, match=r"Dp=256, cap 128\).*VMEM",
+                              iter_bfgs=4)
 
     def test_unknown_sweep_mode_message(self):
         obj, x0 = _starts("sphere", 4, 2, seed=0)

@@ -13,7 +13,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.sharding import (DEFAULT_RULES, logical_to_spec, make_mesh_compat,
+from repro.sharding import (DEFAULT_RULES, logical_to_spec, make_mesh,
                             resolve_axis)
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -31,7 +31,7 @@ def run_subprocess(code: str, devices: int = 8) -> str:
 
 class TestShardingRules:
     def _mesh(self):
-        return make_mesh_compat((1,), ("data",))
+        return make_mesh((1,), ("data",))
 
     def test_divisibility_fallback(self):
         mesh = self._mesh()
@@ -40,7 +40,7 @@ class TestShardingRules:
 
     def test_spec_no_duplicate_mesh_axes(self):
         import jax as _j
-        mesh = make_mesh_compat((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = logical_to_spec(mesh, ("expert", "fsdp", "expert_mlp"),
                                (8, 64, 64))
         flat = []
@@ -54,8 +54,8 @@ class TestShardingRules:
 def test_multi_device_sharding_resolution():
     out = run_subprocess("""
         import jax
-        from repro.sharding import logical_to_spec, make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.sharding import logical_to_spec, make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         # kv_heads=2 does not divide model=4 -> replicated
         spec = logical_to_spec(mesh, ("fsdp", "kv_heads", "head_dim"), (64, 2, 16))
         assert spec[1] is None, spec
@@ -76,8 +76,8 @@ def test_distributed_zeus_multidevice():
         from repro.core import BFGSOptions, PSOOptions, ZeusOptions
         from repro.core.distributed import distributed_zeus
         from repro.core.objectives import sphere
-        from repro.sharding import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.sharding import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         opts = ZeusOptions(pso=PSOOptions(n_particles=128, iter_pso=4),
                            bfgs=BFGSOptions(iter_bfgs=60, theta=1e-4,
                                             required_c=64))
@@ -103,21 +103,22 @@ def test_meanfield_moments_shard_count_invariant():
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.distributed import make_pmoments, shard_map_compat
+        from repro.core.distributed import make_pmoments
         from repro.core.meanfield import consensus_point
         from repro.core.objectives import rastrigin
-        from repro.sharding import make_mesh_compat
+        from repro.sharding import make_mesh
 
         x = jax.random.uniform(jax.random.key(1), (64, 5),
                                minval=-5.12, maxval=5.12)
         fv = jax.vmap(rastrigin)(x)
         want = consensus_point(fv, x, 30.0)  # single-host reduction
         for n_shards in (1, 2, 4, 8):
-            mesh = make_mesh_compat((n_shards,), ("d",))
-            fn = shard_map_compat(
+            mesh = make_mesh((n_shards,), ("d",))
+            fn = jax.shard_map(
                 lambda fv, x: consensus_point(fv, x, 30.0,
                                               make_pmoments(("d",))),
-                mesh, in_specs=(P("d"), P("d")), out_specs=P())
+                mesh=mesh, in_specs=(P("d"), P("d")), out_specs=P(),
+                check_vma=False)
             got = jax.jit(fn)(fv, x)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=1e-6, atol=1e-6)
@@ -127,7 +128,7 @@ def test_meanfield_moments_shard_count_invariant():
                                 ZeusOptions)
         from repro.core.distributed import distributed_zeus
         from repro.core.objectives import sphere
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         opts = ZeusOptions(
             phase1="meanfield",
             meanfield=MeanFieldPSOOptions(n_particles=128, iter_pso=4),
@@ -152,8 +153,8 @@ def test_distributed_repack_and_ladder():
         from repro.core import BFGSOptions, PSOOptions, ZeusOptions
         from repro.core.distributed import distributed_zeus
         from repro.core.objectives import rosenbrock
-        from repro.sharding import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("data",))
+        from repro.sharding import make_mesh
+        mesh = make_mesh((4,), ("data",))
         # rosenbrock over its full range: lanes converge at widely
         # different sweeps, so the tail the repacker compresses actually
         # exists on every shard. required_c must be the GLOBAL lane count:
@@ -197,8 +198,8 @@ def test_distributed_auto_schedule():
         from repro.core import BFGSOptions, PSOOptions, ZeusOptions
         from repro.core.distributed import distributed_zeus
         from repro.core.objectives import rosenbrock
-        from repro.sharding import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("data",))
+        from repro.sharding import make_mesh
+        mesh = make_mesh((4,), ("data",))
         base = dict(use_pso=False,
                     pso=PSOOptions(n_particles=64, iter_pso=0),
                     bfgs=BFGSOptions(iter_bfgs=60, theta=1e-4, ls_iters=10,
@@ -237,8 +238,8 @@ def test_distributed_equals_single_device_semantics():
         from repro.core import BFGSOptions, PSOOptions, ZeusOptions, STOPPED
         from repro.core.distributed import distributed_zeus
         from repro.core.objectives import sphere
-        from repro.sharding import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("data",))
+        from repro.sharding import make_mesh
+        mesh = make_mesh((8,), ("data",))
         opts = ZeusOptions(use_pso=False,
                            pso=PSOOptions(n_particles=64, iter_pso=0),
                            bfgs=BFGSOptions(iter_bfgs=100, theta=1e-12,
@@ -313,8 +314,8 @@ def test_gradient_compression_cross_pod_psum():
         from repro.train.compress import (CompressionConfig,
                                           compress_and_reduce,
                                           init_error_state)
-        from repro.sharding import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("pod",))
+        from repro.sharding import make_mesh
+        mesh = make_mesh((8,), ("pod",))
         ccfg = CompressionConfig(kind="int8")
 
         def shard_step(g_local, e_local):
@@ -324,10 +325,10 @@ def test_gradient_compression_cross_pod_psum():
                                           psum, pmax)
             return red["w"], e["w"]
 
-        from repro.core.distributed import shard_map_compat
-        f = jax.jit(shard_map_compat(shard_step, mesh=mesh,
-                                     in_specs=(P("pod"), P("pod")),
-                                     out_specs=(P("pod"), P("pod"))))
+        f = jax.jit(jax.shard_map(shard_step, mesh=mesh,
+                                  in_specs=(P("pod"), P("pod")),
+                                  out_specs=(P("pod"), P("pod")),
+                                  check_vma=False))
         # per-pod gradient shards (B=8 pods, each holds a (1, 64) slice)
         g = jax.random.normal(jax.random.key(0), (8, 64)) * 1e-2
         e0 = jnp.zeros((8, 64))

@@ -22,7 +22,7 @@ from repro.train.optimizer import (
     init_opt_state,
     lr_schedule,
 )
-from repro.sharding import make_mesh_compat
+from repro.sharding import make_mesh
 from repro.train.step import TrainConfig, init_train_state, make_train_step
 
 KEY = jax.random.key(0)
@@ -170,7 +170,7 @@ class TestCheckpoint:
         """Save on one sharding layout, restore onto another (different
         device partitioning) — the elastic-restart path."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh1 = make_mesh_compat((1,), ("data",))
+        mesh1 = make_mesh((1,), ("data",))
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         ckpt.save(str(tmp_path), 1, tree)
         shard = {"w": NamedSharding(mesh1, P("data", None))}
