@@ -1,0 +1,79 @@
+"""The readings that set the limits of `correct`, on the chip.
+
+    python3 bench/calibrate.py --workload rastrigin-d10.fig1 --seconds 1 \
+        --seeds 101,102,103 --control 3
+
+For each seed, one window of the cell's own traffic at its own size (the
+compiled solve program shared by all seeds), then the readings of the
+program's solves against the reference, and for the first `--control` seeds
+also the readings of the bfloat16 control in the program's place. One JSON
+line per seed: each number as a run reads it over the solves it compares
+(reference.aggregate). The lower reading of a limit is the largest the program
+gives over a dozen seeds or more; the upper, the smallest the control gives.
+"""
+import argparse
+import json
+import sys
+import time
+
+import run
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--control", type=int, default=3)
+    args = ap.parse_args(argv)
+
+    sys.path.insert(0, str(run.BENCH))
+    import spec
+
+    cell = spec.load_cell(args.workload, run.ROOT)
+    run.enable_compile_cache()
+    run.require_chips(cell["chips"])
+    sys.path.insert(0, str(run.ROOT / "src"))
+    for line in calibrate(cell, [int(s) for s in args.seeds.split(",")],
+                          args.seconds, args.control):
+        print(json.dumps(line), flush=True)
+
+
+def calibrate(cell, seeds, seconds, n_control, require_kernel=True):
+    import jax
+
+    import harness
+    import reference
+    from stream import Stream
+
+    cfg = cell["cfg"]
+    problem = harness.spec.problem_module(cfg)
+    log = lambda m: print(m, file=sys.stderr, flush=True)
+    exe = None
+    for k, seed in enumerate(seeds):
+        stream = Stream(cell["mix"], cfg, problem, seed)
+        if exe is None:
+            exe, _ = harness.compile_solve(
+                harness.solve_program(cfg, problem), stream.args(0),
+                require_kernel)
+            for j in range(cfg.get("warmup_solves", 0)):
+                jax.block_until_ready(exe(*stream.warmup_args(j)))
+        t0 = time.perf_counter()
+        win = harness.drive(exe, stream, seconds, harness.no_annotation)
+        answers = harness.collect(win)
+        idx = harness.sample(stream, win)
+        line = {"seed": seed, "solves": len(answers), "compared": len(idx),
+                "window_s": win.elapsed}
+        for tag, control in (("program", False), ("control", True)):
+            if control and k >= n_control:
+                continue
+            per_solve, failed = harness.check(answers, idx, stream, cfg,
+                                              problem, log, control=control)
+            line[tag] = reference.aggregate(per_solve) or None
+            line[tag + "_failed"] = failed
+        line["check_s"] = time.perf_counter() - t0 - win.elapsed
+        yield line
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,323 @@
+"""One run of one cell: compile, window, check, metrics.
+
+`run.py` is the command; this module is the run itself, so that a test can
+drive a whole run at a small size on the CPU with the device check left out.
+"""
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import gc
+import json
+import sys
+import time
+import traceback
+from pathlib import Path
+
+import numpy as np
+
+import reference
+import spec
+import trace_reduce
+from stream import Stream
+
+COMPILE_EVENTS = ("/jax/core/compile", "/jax/compilation_cache")
+# a traced run traces this many seconds of solves at most (the solve in
+# flight then finishes): enough for hundreds of short solves or one long one
+TRACE_SECONDS = 5.0
+
+
+def zeus_options(cfg: dict):
+    """ZeusOptions from the configuration's `zeus` group, field by field."""
+    from repro.core import BFGSOptions, PSOOptions, ZeusOptions
+
+    z = dict(cfg["zeus"])
+    return ZeusOptions(pso=PSOOptions(**z.pop("pso", {})),
+                       bfgs=BFGSOptions(**z.pop("bfgs", {})),
+                       dtype=cfg["dtype"], **z)
+
+
+def solve_program(cfg: dict, problem):
+    """`(key data[, dataset]) -> ZeusResult` through zeus_jit."""
+    import jax
+    from repro.core.zeus import zeus_jit
+
+    opts = zeus_options(cfg)
+    dim, lo, hi = cfg["dim"], float(cfg["lower"]), float(cfg["upper"])
+
+    def solve(raw_key, data=None):
+        f = problem.program_objective(cfg, data)
+        return zeus_jit(f, dim, lo, hi, opts)(jax.random.wrap_key_data(raw_key))
+
+    return solve
+
+
+def compile_solve(solve, example_args, require_kernel: bool):
+    """The solve compiled ahead of time, and its program's text."""
+    import jax
+
+    specs = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype)
+             for a in example_args]
+    exe = jax.jit(solve).lower(*specs).compile()
+    text = exe.as_text()
+    if require_kernel and "tpu_custom_call" not in text:
+        raise RuntimeError("the compiled solve holds no tpu_custom_call: "
+                           "its Pallas kernels did not compile for the chip")
+    return exe, text
+
+
+class CompileCounter:
+    """Counts JAX compile and compile-cache events while armed."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.armed, self.events = False, []
+        mon.register_event_listener(self._on_event)
+        mon.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_event(self, name, **_):
+        if self.armed and name.startswith(COMPILE_EVENTS):
+            self.events.append(name)
+
+    def _on_duration(self, name, _secs, **_):
+        self._on_event(name)
+
+    def close(self):
+        import jax.monitoring as mon
+
+        mon.unregister_event_listener(self._on_event)
+        mon.unregister_event_duration_listener(self._on_duration)
+
+
+@dataclasses.dataclass
+class Window:
+    latencies: list
+    outputs: list  # None where the solve raised
+    elapsed: float
+    t_first: float
+
+
+def drive(exe, stream: Stream, seconds: float, annotate) -> Window:
+    """Closed loop, one caller: solves back to back until `seconds` have
+    passed; the solve in flight then finishes and counts. Each solve is timed
+    from dispatch until its best_x is on the host. The garbage collector is
+    off in the window, so that no collection over the answers it keeps
+    stalls a solve."""
+    gc.collect()
+    gc.freeze()
+    gc.disable()
+    try:
+        return _drive(exe, stream, seconds, annotate)
+    finally:
+        gc.enable()
+        gc.unfreeze()
+
+
+def _drive(exe, stream, seconds, annotate):
+    import jax
+
+    lat, outs = [], []
+    t_first = time.perf_counter()
+    i = 0
+    while True:
+        args = stream.args(i)
+        with annotate("bench.solve"):
+            t0 = time.perf_counter()
+            try:
+                with annotate("bench.dispatch"):
+                    out = exe(*args)
+                with annotate("bench.wait"):
+                    jax.block_until_ready(out)
+                with annotate("bench.readback"):
+                    np.asarray(out.best_x)
+            except Exception:  # a solve that raises counts as failed
+                traceback.print_exc()
+                out = None
+            t1 = time.perf_counter()
+        lat.append(t1 - t0)
+        outs.append(out)
+        i += 1
+        if t1 - t_first >= seconds:
+            return Window(lat, outs, t1 - t_first, t_first)
+
+
+def answer_of(out) -> dict:
+    import jax
+
+    raw = out.raw
+    return jax.device_get({
+        "x": raw.x, "fval": raw.fval, "grad_norm": raw.grad_norm,
+        "status": raw.status, "n_evals": raw.n_evals,
+        "eval_rows": raw.eval_rows,
+        "iterations": raw.iterations, "n_converged": out.n_converged,
+        "best_x": out.best_x, "best_f": out.best_f,
+        "pso_best_f": out.pso_best_f})
+
+
+def device_info(peak_bytes):
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "memory_peak_bytes": peak_bytes}
+
+
+def chips_of(exe) -> list:
+    """Ids of the devices the compiled solve runs on."""
+    import jax
+
+    shardings = jax.tree.leaves((exe.input_shardings, exe.output_shardings))
+    return sorted({d.id for sh in shardings for d in sh.device_set})
+
+
+def program_bytes(exe) -> int:
+    """What one execution of the compiled solve holds on its chip at once:
+    its arguments, outputs and temporaries (less what outputs alias)."""
+    m = exe.memory_analysis()
+    return int(m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes + m.generated_code_size_in_bytes
+               - m.alias_size_in_bytes)
+
+
+def memory_peak(exe, log):
+    """The peak on the fullest chip: the allocator's peak of buffers in use,
+    or the compiled solve's own footprint where that is larger (the
+    allocator's statistics may leave a program's temporaries out)."""
+    import jax
+
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    peak = max(st.get("peak_bytes_in_use", 0) for st in stats)
+    prog = program_bytes(exe)
+    log(f"[memory] allocator peak {peak} B, compiled solve {prog} B; "
+        f"chip 0 stats {stats[0]}")
+    return int(max(peak, prog))
+
+
+def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
+        require_kernel: bool = True, trace_dir: Path | None = None,
+        log=lambda m: print(m, file=sys.stderr, flush=True)) -> dict:
+    """One run; returns the result line's object."""
+    import jax
+
+    cfg, mix = cell["cfg"], cell["mix"]
+    problem = spec.problem_module(cfg)
+    stream = Stream(mix, cfg, problem, seed)
+    solve = solve_program(cfg, problem)
+    exe, text = compile_solve(solve, stream.args(0), require_kernel)
+    for j in range(cfg.get("warmup_solves", 0)):
+        jax.block_until_ready(exe(*stream.warmup_args(j)))
+
+    counter = CompileCounter()
+    tracer = None
+    if trace:
+        tracer = trace_reduce.Tracer(trace_dir, chips_of(exe))
+        tracer.start()
+    counter.armed = True
+    annotate = jax.profiler.TraceAnnotation if trace else no_annotation
+    win = drive(exe, stream, min(seconds, TRACE_SECONDS) if trace else seconds,
+                annotate)
+    counter.armed = False
+    counter.close()
+    setup_s = win.t_first - t_start
+    summary = (tracer.stop(trace_reduce.kernel_names(text)) if tracer
+               else None)
+    if counter.events:
+        raise RuntimeError(f"compilation inside the window: {counter.events}")
+    n = len(win.latencies)
+    log(f"[window] {n} solves in {win.elapsed:.3f}s; setup {setup_s:.3f}s")
+
+    peak = memory_peak(exe, log)
+    answers = collect(win)
+    idx = sample(stream, win)
+    per_solve, failed = check(answers, idx, stream, cfg, problem, log)
+    failed += sum(1 for i, a in enumerate(answers)
+                  if i not in idx and not answered(a))
+    ok, checks = reference.judge(per_solve, cfg["limits"])
+
+    e2e = {"solve_s": win.elapsed / n, "setup_s": setup_s}
+    device = device_info(peak)
+    metrics = {}
+    if trace:
+        device["busy_s"], device["window_s"] = summary.busy_s, summary.window_s
+        ctx = Context(cfg=cfg, trace=summary, problem=problem,
+                      answers=[a for a in answers if a is not None],
+                      device_kind=device["kind"])
+        for m in cell["per_layer"]:
+            v = spec.metric_reader(m["name"]).read(ctx)
+            if v is not None:
+                metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    else:
+        for m in cell["end_to_end"]:
+            metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
+    out = {"correct": bool(ok and failed == 0), "attempted": n,
+           "failed": failed, "metrics": metrics, "device": device}
+    if summary is not None:
+        out["breakdown"] = summary.breakdown
+    out["checks"] = checks
+    return out
+
+
+def collect(win: Window) -> list:
+    """Every answer on the host, and the program's state freed."""
+    answers = [None if o is None else answer_of(o) for o in win.outputs]
+    win.outputs = None
+    return answers
+
+
+def sample(stream: Stream, win: Window) -> list:
+    """The solves compared: a sample drawn from the seed, and the slowest."""
+    n = len(win.latencies)
+    return sorted(set(stream.sample(n)) | {int(np.argmax(win.latencies))})
+
+
+def answered(a) -> bool:
+    return (a is not None and np.isfinite(float(a["best_f"]))
+            and int(a["n_converged"]) > 0)
+
+
+def check(answers, idx, stream, cfg, problem, log, control=False):
+    """Readings of the solves `idx` against the reference (with `control`,
+    of the bfloat16 control in the program's place); (readings, failed)."""
+    per_solve, failed = [], 0
+    for i in idx:
+        a = answers[i]
+        if a is None:
+            failed += 1
+            continue
+        data = stream.data_of(i)
+        draws = reference.pso_draws(stream.args(i)[0], cfg)
+        pso_ref = reference.replay_pso(
+            draws, lambda z: problem.value(z, data, cfg), cfg)
+        if control:
+            a = reference.control_answer(a, problem, cfg, data, draws)
+        r, why = reference.readings(a, problem, cfg, data, pso_ref)
+        if why:
+            failed += 1
+            log(f"[check] solve {i} failed: {why}")
+        else:
+            per_solve.append(r)
+    return per_solve, failed
+
+
+@dataclasses.dataclass
+class Context:
+    """What a per-layer metric reader may read: the configuration, the
+    answers of the traced solves on the host, the trace's Summary, the
+    problem module and the chip's kind."""
+    cfg: dict
+    trace: object
+    problem: object
+    answers: list
+    device_kind: str
+
+
+def no_annotation(_name):
+    return contextlib.nullcontext()
+
+
+def print_result(out: dict):
+    for k, c in out["checks"].items():
+        print(f"check {k} = {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr)
+    print(json.dumps(out), flush=True)
