@@ -116,24 +116,25 @@ def _phase1_shard(
     (global-best bcast), the mean-field swarm through make_pmoments (the
     two-psum consensus) — each strategy's only cross-device traffic."""
     dtype = jnp.dtype(opts.dtype)
-    if not opts.use_pso:
-        # skip the swarm entirely (phase 1 already costs one objective
-        # eval per particle) — same contract as zeus()
-        return uniform_starts(key, n_local, dim, lower, upper, dtype)
-    if opts.phase1 == "meanfield":
-        mf_opts = dataclasses.replace(opts.meanfield, n_particles=n_local)
-        mf = run_meanfield_pso(f, key, dim, lower, upper, mf_opts,
-                               pmoments=make_pmoments(axis_names),
-                               dtype=dtype)
-        # gf is a shard-local running min (reporting only, never part of
-        # the dynamics) — replicate it once at the end
-        return mf.x, jax.lax.pmin(mf.gf, axis_names)
-    pmin = make_pmin(axis_names)
-    state = init_swarm(f, key, n_local, dim, lower, upper, pmin, dtype)
-    state = jax.lax.fori_loop(
-        0, opts.pso.iter_pso,
-        lambda _, s: pso_step(f, s, opts.pso, lower, upper, pmin), state)
-    return state.x, state.gf
+    with jax.named_scope("zeus.phase1"):
+        if not opts.use_pso:
+            # skip the swarm entirely (phase 1 already costs one objective
+            # eval per particle) — same contract as zeus()
+            return uniform_starts(key, n_local, dim, lower, upper, dtype)
+        if opts.phase1 == "meanfield":
+            mf_opts = dataclasses.replace(opts.meanfield, n_particles=n_local)
+            mf = run_meanfield_pso(f, key, dim, lower, upper, mf_opts,
+                                   pmoments=make_pmoments(axis_names),
+                                   dtype=dtype)
+            # gf is a shard-local running min (reporting only, never part of
+            # the dynamics) — replicate it once at the end
+            return mf.x, jax.lax.pmin(mf.gf, axis_names)
+        pmin = make_pmin(axis_names)
+        state = init_swarm(f, key, n_local, dim, lower, upper, pmin, dtype)
+        state = jax.lax.fori_loop(
+            0, opts.pso.iter_pso,
+            lambda _, s: pso_step(f, s, opts.pso, lower, upper, pmin), state)
+        return state.x, state.gf
 
 
 def _local_zeus(
@@ -171,18 +172,19 @@ def _local_zeus(
     # choices the same way: the auto controller decides per shard (its
     # signals are local, collective-free), so row w of the psum'd trace
     # reads "how many shards ran plan p in window w".
-    res = res._replace(n_converged=pcount(res.n_converged),
-                       eval_rows=pcount(res.eval_rows),
-                       map_trips=pcount(res.map_trips),
-                       n_failed=pcount(res.n_failed),
-                       schedule_trace=(pcount(res.schedule_trace)
-                                       if res.schedule_trace is not None
-                                       else None))
+    with jax.named_scope("zeus.finale"):
+        res = res._replace(n_converged=pcount(res.n_converged),
+                           eval_rows=pcount(res.eval_rows),
+                           map_trips=pcount(res.map_trips),
+                           n_failed=pcount(res.n_failed),
+                           schedule_trace=(pcount(res.schedule_trace)
+                                           if res.schedule_trace is not None
+                                           else None))
 
-    # global best among converged lanes
-    best_x, best_f = _select_best(res)
-    best_f, best_x = pmin(best_f, best_x)
-    return best_x, best_f, res, pso_gf
+        # global best among converged lanes
+        best_x, best_f = _select_best(res)
+        best_f, best_x = pmin(best_f, best_x)
+        return best_x, best_f, res, pso_gf
 
 
 def distributed_zeus(
@@ -320,9 +322,10 @@ def distributed_zeus(
     def seg_shard(carry, k_end):
         prog = _shard_program(jnp.zeros((n_local, dim), dtype),
                               make_pcount(axis_names))
-        c = jax.lax.while_loop(
-            lambda cc: jnp.logical_and(prog.cond(cc), cc.k < k_end),
-            prog.body, _unwrap(carry))
+        with jax.named_scope("zeus.phase2"):
+            c = jax.lax.while_loop(
+                lambda cc: jnp.logical_and(prog.cond(cc), cc.k < k_end),
+                prog.body, _unwrap(carry))
         return _wrap(c)
 
     def fin_shard(carry):
@@ -330,16 +333,17 @@ def distributed_zeus(
         pcount = make_pcount(axis_names)
         prog = _shard_program(jnp.zeros((n_local, dim), dtype), pcount)
         res = prog.finalize(_unwrap(carry))
-        res = res._replace(
-            n_converged=pcount(res.n_converged),
-            eval_rows=pcount(res.eval_rows),
-            map_trips=pcount(res.map_trips),
-            n_failed=pcount(res.n_failed),
-            schedule_trace=(pcount(res.schedule_trace)
-                            if res.schedule_trace is not None else None))
-        best_x, best_f = _select_best(res)
-        best_f, best_x = pmin(best_f, best_x)
-        return best_x, best_f, res
+        with jax.named_scope("zeus.finale"):
+            res = res._replace(
+                n_converged=pcount(res.n_converged),
+                eval_rows=pcount(res.eval_rows),
+                map_trips=pcount(res.map_trips),
+                n_failed=pcount(res.n_failed),
+                schedule_trace=(pcount(res.schedule_trace)
+                                if res.schedule_trace is not None else None))
+            best_x, best_f = _select_best(res)
+            best_f, best_x = pmin(best_f, best_x)
+            return best_x, best_f, res
 
     def _elastic_adapt(c: EngineCarry, like_c: EngineCarry, key):
         """Re-derive the wrapped per-shard leaves for a NEW shard count.

@@ -441,47 +441,56 @@ def lane_step(f, vg, strategy: DirectionStrategy, opts: EngineOptions,
     x, fv, g = lane.x, lane.f, lane.g
     active = jnp.logical_not(jnp.logical_or(lane.converged, lane.failed))
 
-    p = strategy.direction(lane.direction_state, g)
-    # Safeguard: if p is not a descent direction (can happen after numerical
-    # breakdown), restart from steepest descent — standard practice.
-    descent = jnp.dot(p, g) < 0
-    p = jnp.where(descent, p, -g)
+    with jax.named_scope("zeus.phase2.update"):
+        p = strategy.direction(lane.direction_state, g)
+    with jax.named_scope("zeus.phase2.ladder"):
+        # Safeguard: if p is not a descent direction (can happen after
+        # numerical breakdown), restart from steepest descent — standard
+        # practice.
+        descent = jnp.dot(p, g) < 0
+        p = jnp.where(descent, p, -g)
 
-    if opts.linesearch == "armijo":
-        ls = armijo_backtracking(
-            f, x, p, fv, g, c1=opts.ls_c1, max_iters=opts.ls_iters
+        if opts.linesearch == "armijo":
+            ls = armijo_backtracking(
+                f, x, p, fv, g, c1=opts.ls_c1, max_iters=opts.ls_iters
+            )
+        elif opts.linesearch == "wolfe":
+            ls = wolfe_linesearch(f, x, p, fv, g, vg,
+                                  max_iters=opts.ls_iters)
+        else:
+            raise ValueError(opts.linesearch)
+
+        x_new = x + ls.alpha * p
+    with jax.named_scope("zeus.phase2.gradient"):
+        f_new, g_new = vg(x_new)
+    with jax.named_scope("zeus.phase2.update"):
+        ds_new = _guarded_update(strategy, lane.direction_state, x_new - x,
+                                 g_new - g)
+
+    with jax.named_scope("zeus.phase2.accept"):
+        gn = jnp.linalg.norm(g_new)
+        now_converged = gn < opts.theta
+        now_failed = jnp.logical_not(
+            jnp.logical_and(jnp.isfinite(f_new), jnp.all(jnp.isfinite(g_new)))
         )
-    elif opts.linesearch == "wolfe":
-        ls = wolfe_linesearch(f, x, p, fv, g, vg, max_iters=opts.ls_iters)
-    else:
-        raise ValueError(opts.linesearch)
 
-    x_new = x + ls.alpha * p
-    f_new, g_new = vg(x_new)
-    ds_new = _guarded_update(strategy, lane.direction_state, x_new - x,
-                             g_new - g)
+        def keep(new, old):
+            return jnp.where(active, new, old)
 
-    gn = jnp.linalg.norm(g_new)
-    now_converged = gn < opts.theta
-    now_failed = jnp.logical_not(
-        jnp.logical_and(jnp.isfinite(f_new), jnp.all(jnp.isfinite(g_new)))
-    )
-
-    def keep(new, old):
-        return jnp.where(active, new, old)
-
-    return Lane(
-        x=keep(x_new, x),
-        f=keep(f_new, fv),
-        g=keep(g_new, g),
-        converged=jnp.where(active, now_converged, lane.converged),
-        failed=jnp.where(active, now_failed, lane.failed),
-        n_evals=lane.n_evals
-        + jnp.where(
-            active, ls.n_evals + grad_eval_cost(x.shape[0], opts.ad_mode), 0
-        ).astype(jnp.int32),
-        direction_state=jax.tree.map(keep, ds_new, lane.direction_state),
-    )
+        return Lane(
+            x=keep(x_new, x),
+            f=keep(f_new, fv),
+            g=keep(g_new, g),
+            converged=jnp.where(active, now_converged, lane.converged),
+            failed=jnp.where(active, now_failed, lane.failed),
+            n_evals=lane.n_evals
+            + jnp.where(
+                active,
+                ls.n_evals + grad_eval_cost(x.shape[0], opts.ad_mode), 0
+            ).astype(jnp.int32),
+            direction_state=jax.tree.map(keep, ds_new,
+                                         lane.direction_state),
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -611,57 +620,62 @@ def batch_lanes_step(bobj, bstrategy: BatchedDirectionStrategy,
     X, F, G, P = lanes.x, lanes.f, lanes.g, lanes.p
     active = jnp.logical_not(jnp.logical_or(lanes.converged, lanes.failed))
 
-    # descent safeguard, rowwise (same rule as the per-lane path)
-    descent = jnp.sum(P * G, axis=-1) < 0
-    P = jnp.where(descent[:, None], P, -G)
+    with jax.named_scope("zeus.phase2.ladder"):
+        # descent safeguard, rowwise (same rule as the per-lane path)
+        descent = jnp.sum(P * G, axis=-1) < 0
+        P = jnp.where(descent[:, None], P, -G)
 
-    ls = armijo_backtracking_batch(
-        bobj.value_batch, X, P, F, G, c1=opts.ls_c1, max_iters=opts.ls_iters,
-        ladder_len=opts.ladder_len,
-    )
-    X_new = X + ls.alpha[:, None] * P
-    F_new, G_new = bobj.value_and_grad_batch(X_new)
-
-    dX, dG = X_new - X, G_new - G
-    curv = jnp.sum(dX * dG, axis=-1)
-    # curvature guard + frozen-lane freeze, lifted to batch level: a single
-    # ok mask decides which lanes' state advances
-    ok = jnp.logical_and(
-        active, jnp.logical_and(jnp.isfinite(curv), curv > _CURV_EPS)
-    )
-    state, P_next = bstrategy.update_and_direction_batch(
-        lanes.direction_state, dX, dG, ok, G_new
-    )
-
-    gn = jnp.linalg.norm(G_new, axis=-1)
-    now_converged = gn < opts.theta
-    now_failed = jnp.logical_not(
-        jnp.logical_and(
-            jnp.isfinite(F_new), jnp.all(jnp.isfinite(G_new), axis=-1)
+        ls = armijo_backtracking_batch(
+            bobj.value_batch, X, P, F, G, c1=opts.ls_c1,
+            max_iters=opts.ls_iters, ladder_len=opts.ladder_len,
         )
-    )
+        X_new = X + ls.alpha[:, None] * P
+    with jax.named_scope("zeus.phase2.gradient"):
+        F_new, G_new = bobj.value_and_grad_batch(X_new)
 
-    def keep(new, old):
-        mask = active.reshape(active.shape + (1,) * (new.ndim - 1))
-        return jnp.where(mask, new, old)
+    with jax.named_scope("zeus.phase2.accept"):
+        dX, dG = X_new - X, G_new - G
+        curv = jnp.sum(dX * dG, axis=-1)
+        # curvature guard + frozen-lane freeze, lifted to batch level: a
+        # single ok mask decides which lanes' state advances
+        ok = jnp.logical_and(
+            active, jnp.logical_and(jnp.isfinite(curv), curv > _CURV_EPS)
+        )
+    with jax.named_scope("zeus.phase2.update"):
+        state, P_next = bstrategy.update_and_direction_batch(
+            lanes.direction_state, dX, dG, ok, G_new
+        )
 
-    stepped = BatchLanes(
-        x=keep(X_new, X),
-        f=keep(F_new, F),
-        g=keep(G_new, G),
-        p=keep(P_next, lanes.p),
-        converged=jnp.where(active, now_converged, lanes.converged),
-        failed=jnp.where(active, now_failed, lanes.failed),
-        n_evals=lanes.n_evals
-        + jnp.where(
-            active, ls.n_evals + bobj.vg_cost(X.shape[-1]), 0
-        ).astype(jnp.int32),
-        direction_state=state,
-    )
-    rows = (ls.n_evals.astype(jnp.int32) + 1) * X.shape[0]
-    hist = jnp.zeros((opts.ls_iters + 1,), jnp.int32).at[ls.rung].add(
-        active.astype(jnp.int32))
-    return stepped, rows, hist
+    with jax.named_scope("zeus.phase2.accept"):
+        gn = jnp.linalg.norm(G_new, axis=-1)
+        now_converged = gn < opts.theta
+        now_failed = jnp.logical_not(
+            jnp.logical_and(
+                jnp.isfinite(F_new), jnp.all(jnp.isfinite(G_new), axis=-1)
+            )
+        )
+
+        def keep(new, old):
+            mask = active.reshape(active.shape + (1,) * (new.ndim - 1))
+            return jnp.where(mask, new, old)
+
+        stepped = BatchLanes(
+            x=keep(X_new, X),
+            f=keep(F_new, F),
+            g=keep(G_new, G),
+            p=keep(P_next, lanes.p),
+            converged=jnp.where(active, now_converged, lanes.converged),
+            failed=jnp.where(active, now_failed, lanes.failed),
+            n_evals=lanes.n_evals
+            + jnp.where(
+                active, ls.n_evals + bobj.vg_cost(X.shape[-1]), 0
+            ).astype(jnp.int32),
+            direction_state=state,
+        )
+        rows = (ls.n_evals.astype(jnp.int32) + 1) * X.shape[0]
+        hist = jnp.zeros((opts.ls_iters + 1,), jnp.int32).at[ls.rung].add(
+            active.astype(jnp.int32))
+        return stepped, rows, hist
 
 
 # ---------------------------------------------------------------------------
@@ -735,70 +749,74 @@ def megakernel_lanes_step(bobj, bstrategy: BatchedDirectionStrategy,
     H = lanes.direction_state
     active = jnp.logical_not(jnp.logical_or(lanes.converged, lanes.failed))
 
-    # descent safeguard, rowwise — same rule, outside the kernel so the
-    # ladder sees exactly the staged path's P
-    descent = jnp.sum(lanes.p * G, axis=-1) < 0
-    P = jnp.where(descent[:, None], lanes.p, -G)
+    with jax.named_scope("zeus.phase2.fused_sweep"):
+        # descent safeguard, rowwise — same rule, outside the kernel so the
+        # ladder sees exactly the staged path's P
+        descent = jnp.sum(lanes.p * G, axis=-1) < 0
+        P = jnp.where(descent[:, None], lanes.p, -G)
 
-    K = opts.ls_iters
-    L = K if opts.ladder_len <= 0 else min(opts.ladder_len, K)
-    if L == K:
-        # full speculative ladder: ONE fused launch. The ladder constants
-        # and the barriered Armijo thresholds are built by the same
-        # linesearch helpers the staged program uses, so the kernel
-        # compares the bit-identical rhs tensor.
-        ddir = jnp.sum(G * P, axis=-1)
-        alphas_np = ladder_alphas(K, X.dtype)
-        rhs = armijo_thresholds(F, ddir, jnp.asarray(alphas_np), opts.ls_c1)
-        X_new, F_new, G_new, state, P_next, _alpha, rung = (
-            kernel_ops.sweep_megakernel_full(
-                name, X, P, G, H, active, rhs, alphas_np))
-        ls_n_evals = jnp.asarray(K, jnp.int32)
-    else:
-        # adaptive ladder: the staged speculative launch + cond-guarded
-        # fallback probes run VERBATIM (their early exit is the point —
-        # see kernels/sweep_megakernel.py on why they stay un-fused), then
-        # everything after the accept fuses into one commit launch.
-        ls = armijo_backtracking_batch(
-            bobj.value_batch, X, P, F, G, c1=opts.ls_c1,
-            max_iters=K, ladder_len=opts.ladder_len,
-        )
-        X_new, F_new, G_new, state, P_next = (
-            kernel_ops.sweep_megakernel_commit(
-                name, X, P, G, H, active, ls.alpha))
-        ls_n_evals, rung = ls.n_evals, ls.rung
+        K = opts.ls_iters
+        L = K if opts.ladder_len <= 0 else min(opts.ladder_len, K)
+        if L == K:
+            # full speculative ladder: ONE fused launch. The ladder constants
+            # and the barriered Armijo thresholds are built by the same
+            # linesearch helpers the staged program uses, so the kernel
+            # compares the bit-identical rhs tensor.
+            ddir = jnp.sum(G * P, axis=-1)
+            alphas_np = ladder_alphas(K, X.dtype)
+            rhs = armijo_thresholds(F, ddir, jnp.asarray(alphas_np),
+                                    opts.ls_c1)
+            X_new, F_new, G_new, state, P_next, _alpha, rung = (
+                kernel_ops.sweep_megakernel_full(
+                    name, X, P, G, H, active, rhs, alphas_np))
+            ls_n_evals = jnp.asarray(K, jnp.int32)
+        else:
+            # adaptive ladder: the staged speculative launch + cond-guarded
+            # fallback probes run VERBATIM (their early exit is the point —
+            # see kernels/sweep_megakernel.py on why they stay un-fused), then
+            # everything after the accept fuses into one commit launch.
+            with jax.named_scope("zeus.phase2.ladder"):
+                ls = armijo_backtracking_batch(
+                    bobj.value_batch, X, P, F, G, c1=opts.ls_c1,
+                    max_iters=K, ladder_len=opts.ladder_len,
+                )
+            X_new, F_new, G_new, state, P_next = (
+                kernel_ops.sweep_megakernel_commit(
+                    name, X, P, G, H, active, ls.alpha))
+            ls_n_evals, rung = ls.n_evals, ls.rung
 
     # epilogue: textually in lockstep with batch_lanes_step (the reference
     # program) — convergence/failure flags, keep-masking, row accounting
-    gn = jnp.linalg.norm(G_new, axis=-1)
-    now_converged = gn < opts.theta
-    now_failed = jnp.logical_not(
-        jnp.logical_and(
-            jnp.isfinite(F_new), jnp.all(jnp.isfinite(G_new), axis=-1)
+    with jax.named_scope("zeus.phase2.accept"):
+        gn = jnp.linalg.norm(G_new, axis=-1)
+        now_converged = gn < opts.theta
+        now_failed = jnp.logical_not(
+            jnp.logical_and(
+                jnp.isfinite(F_new), jnp.all(jnp.isfinite(G_new), axis=-1)
+            )
         )
-    )
 
-    def keep(new, old):
-        mask = active.reshape(active.shape + (1,) * (new.ndim - 1))
-        return jnp.where(mask, new, old)
+        def keep(new, old):
+            mask = active.reshape(active.shape + (1,) * (new.ndim - 1))
+            return jnp.where(mask, new, old)
 
-    stepped = BatchLanes(
-        x=keep(X_new, X),
-        f=keep(F_new, F),
-        g=keep(G_new, G),
-        p=keep(P_next, lanes.p),
-        converged=jnp.where(active, now_converged, lanes.converged),
-        failed=jnp.where(active, now_failed, lanes.failed),
-        n_evals=lanes.n_evals
-        + jnp.where(
-            active, ls_n_evals + bobj.vg_cost(X.shape[-1]), 0
-        ).astype(jnp.int32),
-        direction_state=state,
-    )
-    rows = (ls_n_evals.astype(jnp.int32) + 1) * X.shape[0]
-    hist = jnp.zeros((opts.ls_iters + 1,), jnp.int32).at[rung].add(
-        active.astype(jnp.int32))
-    return stepped, rows, hist
+        stepped = BatchLanes(
+            x=keep(X_new, X),
+            f=keep(F_new, F),
+            g=keep(G_new, G),
+            p=keep(P_next, lanes.p),
+            converged=jnp.where(active, now_converged, lanes.converged),
+            failed=jnp.where(active, now_failed, lanes.failed),
+            n_evals=lanes.n_evals
+            + jnp.where(
+                active, ls_n_evals + bobj.vg_cost(X.shape[-1]), 0
+            ).astype(jnp.int32),
+            direction_state=state,
+        )
+        rows = (ls_n_evals.astype(jnp.int32) + 1) * X.shape[0]
+        hist = jnp.zeros((opts.ls_iters + 1,), jnp.int32).at[rung].add(
+            active.astype(jnp.int32))
+        return stepped, rows, hist
 
 
 # ---------------------------------------------------------------------------
@@ -1159,6 +1177,21 @@ def _freeze_config(strategy) -> Tuple:
         for k, v in sorted(cfg.items()))
 
 
+def _in_phase2(fn: Callable) -> Callable:
+    """`fn` traced under the `zeus.phase2` name scope, whichever loop
+    calls it: the in-graph loop, a shard_map program, a jitted host
+    segment. A fresh scope per call: one `jax.named_scope` object used as
+    a decorator keeps a single saved context, which nested traces of the
+    same function would restore wrongly."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("zeus.phase2"):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
 def run_multistart(
     f: Callable,
     x0: jnp.ndarray,  # (B, D) starting points (the post-PSO swarm)
@@ -1189,7 +1222,14 @@ def run_multistart(
     """
     B, D = x0.shape
     required_c = opts.required_c if opts.required_c is not None else B
-    count = pcount if pcount is not None else (lambda c: c)
+
+    def count(c):
+        """The stop protocol's global count: a psum across the mesh under
+        distributed_zeus, the local count otherwise."""
+        if pcount is None:
+            return c
+        with jax.named_scope("zeus.phase2.stop"):
+            return pcount(c)
 
     if opts.compact_every < 0:
         raise ValueError(f"compact_every must be >= 0 (got {opts.compact_every})")
@@ -1679,6 +1719,7 @@ def run_multistart(
                 hist=jnp.zeros_like(astate.hist),  # window accumulator reset
             )
 
+        @_in_phase2
         def sched_body(carry):
             k = carry.k
             lanes, rkey, n_restarts, rrows, force = _prologue(carry)
@@ -1886,12 +1927,14 @@ def run_multistart(
             force = jnp.logical_or(force, retried)
         return lanes, rkey, n_restarts, rrows, force
 
+    @_in_phase2
     def cond(carry):
         return jnp.logical_and(
             carry.k < opts.iter_max,
             jnp.logical_and(carry.n_conv < required_c, carry.n_act > 0),
         )
 
+    @_in_phase2
     def body(carry):
         k = carry.k
         lanes, rkey, n_restarts, rrows, force = _prologue(carry)
@@ -1938,6 +1981,7 @@ def run_multistart(
     else:
         rkey0 = jnp.asarray(retry_key, jnp.uint32)
 
+    @_in_phase2
     def make_carry0(X=None, rk=None):
         # the optional args exist for the hosted driver's cross-call jit
         # cache (start values become traced inputs instead of baked
@@ -1953,6 +1997,7 @@ def run_multistart(
             n_restarts=n_restarts0, replan=jnp.zeros((), bool),
             deadline=jnp.zeros((B_flat,), jnp.int32), telem=telem0)
 
+    @_in_phase2
     def finalize(carry):
         k, lanes = carry.k, carry.lanes
         schedule_trace = carry.astate.trace if scheduling else None
@@ -1995,6 +2040,7 @@ def run_multistart(
     # pool, and `lane_view` is the per-slot harvest read at a segment
     # boundary.
     # ------------------------------------------------------------------
+    @_in_phase2
     def admit_lanes(carry, mask, X, deadlines):
         """Seed fresh lanes at X rows into the mask'd flat slots.
 
@@ -2024,6 +2070,7 @@ def run_multistart(
             n_restarts=n_restarts, deadline=deadline,
             replan=jnp.logical_or(carry.replan, any_m))
 
+    @_in_phase2
     def vacate_lanes(carry):
         """Freeze every slot (failed, not converged): the service's empty
         initial pool. Admissions then light slots back up one by one."""
@@ -2035,6 +2082,7 @@ def run_multistart(
         n_conv, n_act = counts(lanes, carry.n_restarts)
         return carry._replace(lanes=lanes, n_conv=n_conv, n_act=n_act)
 
+    @_in_phase2
     def lane_view(carry):
         """Flat per-slot harvest view. grad_norm is computed on-device the
         same way finalize's is, so a harvested result is array-equal to
@@ -2059,7 +2107,8 @@ def run_multistart(
                                  opts=opts, required_c=required_c)
 
     if not hosted and not _as_host:
-        return finalize(jax.lax.while_loop(cond, step_body, make_carry0()))
+        with jax.named_scope("zeus.phase2"):
+            return finalize(jax.lax.while_loop(cond, step_body, make_carry0()))
 
     # ------------------------------------------------------------------
     # Host-segmented driver (checkpoint / preempt / resume): run the SAME
@@ -2085,9 +2134,9 @@ def run_multistart(
     if cached is None:
         cached = (
             jax.jit(lambda X, rk: make_carry0(X, rk)),
-            jax.jit(lambda c, k_end: jax.lax.while_loop(
+            jax.jit(_in_phase2(lambda c, k_end: jax.lax.while_loop(
                 lambda cc: jnp.logical_and(cond(cc), cc.k < k_end),
-                step_body, c)),
+                step_body, c))),
             jax.jit(finalize),
             # the loop evaluates cond on the host between segments; eager
             # op-by-op dispatch of its reductions costs more than the

@@ -8,6 +8,10 @@ name from the solver registry, `lane_chunk=C` bounds phase-2 transient
 memory to O(C·D²) via chunked lane execution.
 Finale:  parallel reduction for the best converged iterate (Alg. 7 line 10)
 plus the §VII-B confidence clustering, realized in core/clustering.py.
+
+Each stage traces under a `jax.named_scope` (`zeus.phase1`, `zeus.phase2`
+and its sweep stages `zeus.phase2.<stage>`, `zeus.finale`), so every op of
+the compiled solve carries its stage in its `op_name` (DESIGN.md §19).
 """
 from __future__ import annotations
 
@@ -124,16 +128,17 @@ def run_phase1(f, key, dim, lower, upper, opts: ZeusOptions, dtype,
         raise ValueError(
             f"unknown phase1 strategy {opts.phase1!r}; expected one of "
             f"{PHASE1_STRATEGIES}")
-    if not opts.use_pso:
-        return uniform_starts(
-            key, phase1_particles(opts), dim, lower, upper, dtype)
-    if opts.phase1 == "meanfield":
-        mf = run_meanfield_pso(f, key, dim, lower, upper, opts.meanfield,
-                               pmoments=pmoments, dtype=dtype)
-        return mf.x, mf.gf
-    swarm = run_pso(f, key, dim, lower, upper, opts.pso, pmin=pmin,
-                    dtype=dtype)
-    return swarm.x, swarm.gf
+    with jax.named_scope("zeus.phase1"):
+        if not opts.use_pso:
+            return uniform_starts(
+                key, phase1_particles(opts), dim, lower, upper, dtype)
+        if opts.phase1 == "meanfield":
+            mf = run_meanfield_pso(f, key, dim, lower, upper, opts.meanfield,
+                                   pmoments=pmoments, dtype=dtype)
+            return mf.x, mf.gf
+        swarm = run_pso(f, key, dim, lower, upper, opts.pso, pmin=pmin,
+                        dtype=dtype)
+        return swarm.x, swarm.gf
 
 
 def _solver_name(opts: ZeusOptions) -> str:
@@ -247,11 +252,12 @@ def uniform_starts(key, n: int, dim: int, lower: float, upper: float, dtype):
 
 def _select_best(res: BFGSResult) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Parallel reduction: best *converged* lane; fall back to best overall."""
-    fv = jnp.where(res.status == engine_mod.CONVERGED, res.fval, jnp.inf)
-    any_conv = jnp.any(jnp.isfinite(fv))
-    fv = jnp.where(any_conv, fv, res.fval)
-    i = jnp.argmin(fv)
-    return res.x[i], fv[i]
+    with jax.named_scope("zeus.finale"):
+        fv = jnp.where(res.status == engine_mod.CONVERGED, res.fval, jnp.inf)
+        any_conv = jnp.any(jnp.isfinite(fv))
+        fv = jnp.where(any_conv, fv, res.fval)
+        i = jnp.argmin(fv)
+        return res.x[i], fv[i]
 
 
 def zeus(
