@@ -19,7 +19,9 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.bfgs_update import (
     bfgs_update_pallas,
+    guarded_update_direction_lanes_pallas,
     guarded_update_direction_pallas,
+    lane_minor,
     update_direction_pallas,
 )
 from repro.kernels.direction import direction_pallas
@@ -137,10 +139,20 @@ def guarded_update_direction(H, dx, dg, g_new, rho):
     rho (B,) is the precomputed curvature factor 1/(δxᵀδg), already zeroed
     for lanes whose update is disabled (curvature guard or frozen lane) —
     with ρ = 0 and zeroed (δx, δg) the update is exactly H' = H, so the
-    guard costs no second read of H. Returns (H', p' = -H' g_new)."""
+    guard costs no second read of H. Returns (H', p' = -H' g_new).
+
+    Up to bfgs_update.LANE_MINOR_MAX_DIM the kernel takes the stack
+    lane-minor and unpadded in D: H as (D, D, B), row j of every lane's H
+    in H[j], and the vectors as (D, B). The transposes to and from the
+    engine's (B, D, D) / (B, D) stay here."""
     if not _use_pallas():
         return ref.guarded_update_direction_ref(H, dx, dg, g_new, rho)
     B, D, _ = H.shape
+    if lane_minor(D):
+        Hn, p = guarded_update_direction_lanes_pallas(
+            jnp.transpose(H, (1, 2, 0)), dx.T, dg.T, g_new.T, rho,
+            interpret=_interpret())
+        return jnp.transpose(Hn, (2, 0, 1)), p.T
     Dp = _padded_dim(D)
     Hp = _pad_to(_pad_to(H, Dp, 1), Dp, 2)
     Hn, p = guarded_update_direction_pallas(

@@ -29,19 +29,28 @@ Stage layout per grid step (one lane — see "why one lane" below):
                 guard ρ (sliced to the TRUE dim so the reduction has the
                 same length and order as the staged path's out-of-kernel
                 `jnp.sum(dX·dG, -1)`), then the guarded ρ-form H' update
-                and p' = −H'·g' through bfgs_update.update_direction_body —
-                the very body the staged `_guarded_update_direction_kernel`
-                runs, at the same (Dp, Dp)×(Dp, 1) dot shapes.
+                and p' = −H'·g' through bfgs_update.lane_update_direction,
+                which takes the rule the staged
+                `_guarded_update_direction_kernel` takes at the true D:
+                row by row over the true D (bfgs_update.
+                update_direction_rows, the staged kernel's own per-lane
+                arithmetic on its lane-minor tile) up to
+                bfgs_update.LANE_MINOR_MAX_DIM, else the MXU body at the
+                staged kernel's (Dp, Dp)×(Dp, 1) dot shapes.
 
 Why one lane per grid step: exactness. Every reduction in the staged path
-is either per-row (objective bodies), per-lane at (Dp, Dp)×(Dp, 1) (the
-update kernel, grid=(B,)), or out-of-kernel over the true D (curv, ddir).
-Reproducing those exact shapes per grid step makes each lane's arithmetic
-independent of B and bit-identical to the staged program wherever the
-backend's reductions are length-stable — the same batch-size-stability
-contract compaction already leans on. A lane-tile variant would batch the
-update matvecs into (TB, Dp, Dp)×(TB, Dp) dot_generals whose per-lane
-rounding the staged kernels never produce.
+is either per-row (objective bodies), per-lane (the update kernel: a chain
+of multiply-adds over the true D in ascending order, or a (Dp, Dp)×(Dp, 1)
+dot per lane above the lane-minor bound), or out-of-kernel over the true D
+(curv, ddir). Reproducing each lane's own op sequence per grid step makes
+its arithmetic independent of B and bit-identical to the staged program
+wherever the backend's reductions are length-stable — the same
+batch-size-stability contract compaction already leans on. The staged
+update is free to put TB lanes on the minor axis because its per-lane
+arithmetic has no cross-lane term; the megakernel's other stages are not
+(the ladder's (K, Dp) trial fan and the objective bodies are one lane's
+rows here), so it keeps one lane per step and runs the update's row chain
+on that lane's (Dp, Dp) tile.
 
 Why the sequential fallback stays un-fused (PR 4 semantics): when
 0 < ladder_len < K the staged adaptive ladder's fallback probes are
@@ -69,8 +78,10 @@ the engine's megakernel step delegates wholesale to `batch_lanes_step` —
 the staged program IS the megakernel's reference semantics, bit-for-bit.
 The interpret leg (CPU) runs the real fused bodies below; the
 `jax.lax.optimization_barrier`s inside the body sit at exactly the staged
-program's materialization points (pallas_call input/output boundaries), so
-XLA cannot re-fuse across a stage seam the staged program keeps.
+program's materialization points (pallas_call input/output boundaries).
+XLA's CPU pipeline drops barriers before it fuses, so the update's row
+chain does not lean on them: update_direction_rows keeps the CPU backend's
+FMA contraction from rounding a lane differently in the two programs.
 """
 from __future__ import annotations
 
@@ -80,7 +91,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bfgs_update import (_lane_rows, update_direction_body,
+from repro.kernels.bfgs_update import (_lane_rows, lane_update_direction,
                                        vmem_params)
 from repro.kernels.fused_obj import objective_body
 
@@ -104,10 +115,11 @@ def _seam_fn(interpret):
     return _barrier if interpret else (lambda x: x)
 
 
-def _commit_tail(body, _seam, d, x, p, g, H, act, alpha):
+def _commit_tail(body, interpret, d, x, p, g, H, act, alpha):
     """Stage 4, shared by both kernels: step, value+grad, guard, H', p'.
 
     All inputs are one lane's (Dp,)/(Dp, Dp) rows; `d` is the true dim."""
+    _seam = _seam_fn(interpret)
     x_new = _seam(x + alpha * p)
     f_new, g_row = body(x_new[None, :], with_grad=True)
     f_new, g_new = _seam(f_new[0]), _seam(g_row[0])
@@ -124,11 +136,12 @@ def _commit_tail(body, _seam, d, x, p, g, H, act, alpha):
     rho = _seam(jnp.where(ok, 1.0 / jnp.where(ok, curv, 1.0), 0.0))
     dxs = _seam(jnp.where(ok, dx, 0.0))
     dgs = _seam(jnp.where(ok, dg, 0.0))
-    h_new, p_new = update_direction_body(H, dxs, dgs, g_new, rho)
+    h_new, p_new = lane_update_direction(H, dxs, dgs, g_new, rho, d,
+                                         interpret)
     return x_new, f_new, g_new, h_new, p_new
 
 
-def _full_sweep_kernel(body, _seam, d, exhaust_alpha, K,
+def _full_sweep_kernel(body, interpret, d, exhaust_alpha, K,
                        x_ref, p_ref, g_ref, h_ref, act_ref, rhs_ref,
                        al_ref,
                        xo_ref, fo_ref, go_ref, ho_ref, po_ref,
@@ -138,6 +151,7 @@ def _full_sweep_kernel(body, _seam, d, exhaust_alpha, K,
     al (K, 1) the host ladder constants (an input because pallas kernels
     can't close over array constants — values still host-computed by
     linesearch.ladder_alphas)."""
+    _seam = _seam_fn(interpret)
     x = x_ref[0, 0]
     p = p_ref[0, 0]
     act = act_ref[0, 0, 0] != 0
@@ -160,7 +174,7 @@ def _full_sweep_kernel(body, _seam, d, exhaust_alpha, K,
 
     # stage 4: commit + guarded H-update + next direction
     x_new, f_new, g_new, h_new, p_new = _commit_tail(
-        body, _seam, d, x, p, g_ref[0, 0], h_ref[0], act, alpha)
+        body, interpret, d, x, p, g_ref[0, 0], h_ref[0], act, alpha)
     _store_lane(xo_ref, fo_ref, go_ref, ho_ref, po_ref,
                 x_new, f_new, g_new, h_new, p_new)
     _store_scalar(ao_ref, alpha)
@@ -180,13 +194,13 @@ def _store_lane(xo_ref, fo_ref, go_ref, ho_ref, po_ref,
     po_ref[0, 0] = p_new.astype(po_ref.dtype)
 
 
-def _commit_kernel(body, _seam, d,
+def _commit_kernel(body, interpret, d,
                    x_ref, p_ref, g_ref, h_ref, act_ref, alpha_ref,
                    xo_ref, fo_ref, go_ref, ho_ref, po_ref):
     """Short-ladder commit: stage 4 only, α decided by the staged adaptive
     ladder (launch #1). One lane per grid step, same blocks as above."""
     outs = _commit_tail(
-        body, _seam, d, x_ref[0, 0], p_ref[0, 0], g_ref[0, 0], h_ref[0],
+        body, interpret, d, x_ref[0, 0], p_ref[0, 0], g_ref[0, 0], h_ref[0],
         act_ref[0, 0, 0] != 0, alpha_ref[0, 0, 0])
     _store_lane(xo_ref, fo_ref, go_ref, ho_ref, po_ref, *outs)
 
@@ -234,7 +248,7 @@ def sweep_megakernel_full_pallas(name, X, P, G, H, active, rhs, alphas_np,
     exhaust_alpha = npdt(alphas_np[-1] * npdt(shrink))  # staged alphas[-1]·shrink
     vec, mat, scl = _lane_specs(D)
     kernel = functools.partial(
-        _full_sweep_kernel, body, _seam_fn(interpret), d, exhaust_alpha, K)
+        _full_sweep_kernel, body, interpret, d, exhaust_alpha, K)
     outs = pl.pallas_call(
         kernel,
         grid=(B,),
@@ -262,7 +276,7 @@ def sweep_megakernel_commit_pallas(name, X, P, G, H, active, alpha,
     d = dim if dim is not None else D
     body = objective_body(name, d)
     vec, mat, scl = _lane_specs(D)
-    kernel = functools.partial(_commit_kernel, body, _seam_fn(interpret), d)
+    kernel = functools.partial(_commit_kernel, body, interpret, d)
     outs = pl.pallas_call(
         kernel,
         grid=(B,),

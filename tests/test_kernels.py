@@ -10,6 +10,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.bfgs_update import LANE_MINOR_MAX_DIM
 
 
 def _spd_hessians(key, B, D, dtype):
@@ -58,12 +59,13 @@ class TestBFGSUpdateKernel:
         np.testing.assert_allclose(np.asarray(p), np.asarray(pr),
                                    rtol=3e-4, atol=2e-3)
 
-    def test_guarded_rho_zero_keeps_h_exactly(self):
+    @pytest.mark.parametrize("D", [12, 130])  # lane-minor, per-lane MXU
+    def test_guarded_rho_zero_keeps_h_exactly(self, D):
         """ρ = 0 with zeroed pairs must leave H bitwise unchanged and emit
         p = -H g — that is how the engine's curvature guard and frozen-lane
         masking lift into the kernel with no second read of H."""
-        H, dx, dg = _spd_hessians(jax.random.key(7), 3, 12, jnp.float32)
-        gn = jax.random.normal(jax.random.key(8), (3, 12))
+        H, dx, dg = _spd_hessians(jax.random.key(7), 3, D, jnp.float32)
+        gn = jax.random.normal(jax.random.key(8), (3, D))
         rho = (1.0 / jnp.sum(dx * dg, axis=-1)).at[1].set(0.0)
         dx = dx.at[1].set(0.0)
         dg = dg.at[1].set(0.0)
@@ -90,6 +92,59 @@ class TestBFGSUpdateKernel:
         expect = ref.bfgs_update_ref(H, dx, dg)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                    rtol=5e-3, atol=5e-3)
+
+
+def _guarded_args(B, D, seed):
+    H, dx, dg = _spd_hessians(jax.random.key(seed), B, D, jnp.float32)
+    gn = jax.random.normal(jax.random.key(seed + 1), (B, D))
+    return H, dx, dg, gn, 1.0 / jnp.sum(dx * dg, axis=-1)
+
+
+def _pallas_out_shapes(B, D):
+    """Output shapes of the Pallas call ops.guarded_update_direction makes."""
+    args = [jax.ShapeDtypeStruct(s, jnp.float32)
+            for s in ((B, D, D), (B, D), (B, D), (B, D), (B,))]
+    eqns = [e for e in jax.make_jaxpr(ops.guarded_update_direction)(
+        *args).eqns if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1
+    return [tuple(v.aval.shape) for v in eqns[0].outvars]
+
+
+class TestLaneMinorGuardedUpdate:
+    """Up to LANE_MINOR_MAX_DIM the guarded update runs with lanes on the
+    minor axis and D unpadded (bfgs_update.update_direction_rows)."""
+
+    @pytest.mark.parametrize("B", [1, 7, 128, 300])  # 300: lane padding
+    @pytest.mark.parametrize("D", [1, 2, 10, LANE_MINOR_MAX_DIM])
+    def test_matches_reference(self, B, D):
+        args = _guarded_args(B, D, seed=B * 131 + D)
+        Hn, p = ops.guarded_update_direction(*args)
+        Hr, pr = ref.guarded_update_direction_ref(*args)
+        assert Hn.shape == (B, D, D) and p.shape == (B, D)
+        np.testing.assert_allclose(np.asarray(Hn), np.asarray(Hr),
+                                   rtol=3e-4, atol=3e-4)
+        np.testing.assert_allclose(np.asarray(p), np.asarray(pr),
+                                   rtol=3e-4, atol=2e-3)
+
+    @pytest.mark.parametrize("D", [3, 10])
+    def test_lane_independent_of_batch(self, D):
+        """A lane's H', p' are array-equal whether it rides alone, in a
+        batch of 7 (a 128-lane tile) or of 300 (a 384-lane tile):
+        compaction's contract."""
+        args = _guarded_args(300, D, seed=D)
+        Hn, p = ops.guarded_update_direction(*args)
+        for b in (1, 7):
+            Hb, pb = ops.guarded_update_direction(*(a[:b] for a in args))
+            np.testing.assert_array_equal(np.asarray(Hb), np.asarray(Hn[:b]))
+            np.testing.assert_array_equal(np.asarray(pb), np.asarray(p[:b]))
+
+    @pytest.mark.parametrize("B,D,shapes", [
+        (8, 10, [(10, 10, 128), (10, 128)]),  # lanes minor, padded to 128
+        (8, 128, [(8, 128, 128), (8, 1, 128)]),  # one lane per grid step
+        (8, LANE_MINOR_MAX_DIM + 1, [(8, 128, 128), (8, 1, 128)]),
+    ])
+    def test_layout_is_chosen_by_dim(self, B, D, shapes):
+        assert _pallas_out_shapes(B, D) == shapes
 
 
 class TestDirectionKernel:

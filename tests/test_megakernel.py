@@ -73,7 +73,7 @@ class TestMegakernelParity:
     """Array-equal vs the staged batched path, both Pallas-dispatch legs."""
 
     @pytest.mark.parametrize("name,dim", [
-        ("sphere", 4), ("rastrigin", 3), ("ackley", 3)])
+        ("sphere", 4), ("rastrigin", 3), ("ackley", 3), ("rastrigin", 10)])
     def test_full_ladder_exact(self, name, dim):
         """ladder_len=0: the ONE-launch fused path on every fused objective
         (rosenbrock needs 128-aligned D — covered separately)."""

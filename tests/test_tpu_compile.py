@@ -64,6 +64,14 @@ def test_guarded_update_direction(one_chip, B, D):
              ((B,), f32), sharding=one_chip)
 
 
+@pytest.mark.parametrize("B,D", [(8192, 10), (8, 10),
+                                 (128, bfgs_update.LANE_MINOR_MAX_DIM)])
+def test_guarded_update_direction_lane_minor(one_chip, B, D):
+    _compile(bfgs_update.guarded_update_direction_lanes_pallas,
+             ((D, D, B), f32), ((D, B), f32), ((D, B), f32), ((D, B), f32),
+             ((B,), f32), sharding=one_chip)
+
+
 def test_direction(one_chip):
     _compile(direction.direction_pallas, ((8192, 128, 128), f32),
              ((8192, 128), f32), sharding=one_chip)
