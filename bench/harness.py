@@ -23,8 +23,11 @@ from stream import Stream
 
 COMPILE_EVENTS = ("/jax/core/compile", "/jax/compilation_cache")
 # a traced run traces this many seconds of solves at most (the solve in
-# flight then finishes): enough for hundreds of short solves or one long one
+# flight then finishes), and this many solves at most: enough for one long
+# solve or a dozen short ones, while the profiler's trace of short solves
+# (the dijet fit: ~32,000 device ops each) stays quick to write and reduce
 TRACE_SECONDS = 5.0
+TRACE_SOLVES = 16
 
 
 def zeus_options(cfg: dict):
@@ -52,17 +55,31 @@ def solve_program(cfg: dict, problem):
     return solve
 
 
-def compile_solve(solve, example_args, require_kernel: bool):
-    """The solve compiled ahead of time, and its program's text."""
+def precision(cfg: dict):
+    """The context a run of `cfg` lives in: JAX's 64-bit mode on exactly
+    where the configuration's `dtype` is float64, so that its inputs, its
+    compiled solve, the window and the reference's swarm draws all hold the
+    precision it states. The mode is restored on leaving."""
+    import jax
+
+    return jax.enable_x64(cfg["dtype"] == "float64")
+
+
+def compile_solve(solve, example_args, kernels=()):
+    """The solve compiled ahead of time, and its program's text. Each Pallas
+    kernel function named in `kernels` must be in the compiled program
+    (trace_reduce.kernel_names), or its path fell back to XLA."""
     import jax
 
     specs = [jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype)
              for a in example_args]
     exe = jax.jit(solve).lower(*specs).compile()
     text = exe.as_text()
-    if require_kernel and "tpu_custom_call" not in text:
-        raise RuntimeError("the compiled solve holds no tpu_custom_call: "
-                           "its Pallas kernels did not compile for the chip")
+    missing = sorted(set(kernels)
+                     - set(trace_reduce.kernel_names(text).values()))
+    if missing:
+        raise RuntimeError(f"the compiled solve lacks the Pallas kernels "
+                           f"{missing}: they did not compile for the chip")
     return exe, text
 
 
@@ -98,23 +115,24 @@ class Window:
     t_first: float
 
 
-def drive(exe, stream: Stream, seconds: float, annotate) -> Window:
+def drive(exe, stream: Stream, seconds: float, annotate,
+          max_solves=None) -> Window:
     """Closed loop, one caller: solves back to back until `seconds` have
-    passed; the solve in flight then finishes and counts. Each solve is timed
-    from dispatch until its best_x is on the host. The garbage collector is
-    off in the window, so that no collection over the answers it keeps
-    stalls a solve."""
+    passed (or `max_solves` have run); the solve in flight then finishes and
+    counts. Each solve is timed from dispatch until its best_x is on the
+    host. The garbage collector is off in the window, so that no collection
+    over the answers it keeps stalls a solve."""
     gc.collect()
     gc.freeze()
     gc.disable()
     try:
-        return _drive(exe, stream, seconds, annotate)
+        return _drive(exe, stream, seconds, annotate, max_solves)
     finally:
         gc.enable()
         gc.unfreeze()
 
 
-def _drive(exe, stream, seconds, annotate):
+def _drive(exe, stream, seconds, annotate, max_solves):
     import jax
 
     lat, outs = [], []
@@ -138,7 +156,7 @@ def _drive(exe, stream, seconds, annotate):
         lat.append(t1 - t0)
         outs.append(out)
         i += 1
-        if t1 - t_first >= seconds:
+        if t1 - t_first >= seconds or i == max_solves:
             return Window(lat, outs, t1 - t_first, t_first)
 
 
@@ -197,14 +215,22 @@ def memory_peak(exe, log):
 def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         require_kernel: bool = True, trace_dir: Path | None = None,
         log=lambda m: print(m, file=sys.stderr, flush=True)) -> dict:
-    """One run; returns the result line's object."""
+    """One run; returns the result line's object. `require_kernel` False
+    leaves out the check for the configuration's `kernels` (CPU tests)."""
+    with precision(cell["cfg"]):
+        return _run(cell, seed, seconds, trace, t_start, require_kernel,
+                    trace_dir, log)
+
+
+def _run(cell, seed, seconds, trace, t_start, require_kernel, trace_dir, log):
     import jax
 
     cfg, mix = cell["cfg"], cell["mix"]
     problem = spec.problem_module(cfg)
     stream = Stream(mix, cfg, problem, seed)
     solve = solve_program(cfg, problem)
-    exe, text = compile_solve(solve, stream.args(0), require_kernel)
+    exe, text = compile_solve(solve, stream.args(0),
+                              cfg["kernels"] if require_kernel else ())
     for j in range(cfg.get("warmup_solves", 0)):
         jax.block_until_ready(exe(*stream.warmup_args(j)))
 
@@ -215,8 +241,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         tracer.start()
     counter.armed = True
     annotate = jax.profiler.TraceAnnotation if trace else no_annotation
-    win = drive(exe, stream, min(seconds, TRACE_SECONDS) if trace else seconds,
-                annotate)
+    win = (drive(exe, stream, min(seconds, TRACE_SECONDS), annotate,
+                 TRACE_SOLVES) if trace else
+           drive(exe, stream, seconds, annotate))
     counter.armed = False
     counter.close()
     setup_s = win.t_first - t_start

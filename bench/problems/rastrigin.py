@@ -35,7 +35,8 @@ def vg_cost(cfg):
 
 def row_work(cfg):
     """Required (flops, bytes) of one value row and of one value+grad row at
-    the unpadded D, float32: per coordinate 2πx, cos, x², scale, subtract
-    and the sum's add; the gradient adds sin, scale, 2x and an add."""
-    d, b = cfg["dim"], 4
+    the unpadded D, in the configuration's dtype: per coordinate 2πx, cos,
+    x², scale, subtract and the sum's add; the gradient adds sin, scale, 2x
+    and an add."""
+    d, b = cfg["dim"], np.dtype(cfg["dtype"]).itemsize
     return {"value": (6 * d, d * b + b), "value_grad": (11 * d, d * b + b + d * b)}

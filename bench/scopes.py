@@ -73,10 +73,9 @@ def program_text(cfg: dict, problem) -> str:
         if data is not None:
             args += (data,)
         t0 = time.perf_counter()
-        with _no_persistent_cache():
+        with _no_persistent_cache(), harness.precision(cfg):
             _, _texts[key] = harness.compile_solve(
-                harness.solve_program(cfg, problem), args,
-                require_kernel=False)
+                harness.solve_program(cfg, problem), args)
         print(f"[scopes] program text compiled in "
               f"{time.perf_counter() - t0:.3f}s", file=sys.stderr, flush=True)
     return _texts[key]

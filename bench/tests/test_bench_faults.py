@@ -8,10 +8,16 @@ underneath, it comes out not correct, once for each fault a cell can have.
   their swarm start;
 - early_stop: the sweep loop stops after half of its sweeps;
 - altered: the answer altered where it is produced (best_x moved off the
-  best lane).
+  best lane);
+- f32_build (float64 cells): the same configuration built in float32;
+- f32_replay (float64 cells): the reference's swarm drawn in float32
+  against the float64 program's.
 
-The cells run on one chip, so there is no exchange between chips to leave
-out.
+A per-lane cell has no skip_half (its step is vmapped over single lanes,
+so no step sees a batch to halve) and no early_stop (the dijet fit stops
+on required_c after ~15 of its 300 sweeps, so halving iter_max changes
+nothing). Each cell's solve runs on one chip, so there is no exchange
+between chips to leave out.
 """
 import dataclasses
 import importlib
@@ -23,6 +29,8 @@ import jax.numpy as jnp
 import pytest
 
 import harness
+import reference
+import work
 
 SEED = 2**31 + 3
 
@@ -37,6 +45,8 @@ def frozen(monkeypatch):
         return lanes, rows, hist
 
     monkeypatch.setattr(engine, "batch_lanes_step", unchanged)
+    monkeypatch.setattr(engine, "lane_step",
+                        lambda f, vg, strategy, opts, lane: lane)
 
 
 def half(monkeypatch):
@@ -98,10 +108,22 @@ def altered(monkeypatch):
                         lambda res: (lambda x, f: (x + 0.05, f))(*select(res)))
 
 
-def _run(name):
-    return harness.run(_tiny.tiny_cell(name), SEED, 0.5, False,
-                       time.perf_counter(), require_kernel=False,
-                       log=lambda m: None)
+def f32_build(cell, monkeypatch):
+    cell["cfg"]["dtype"] = "float32"
+
+
+def f32_replay(cell, monkeypatch):
+    draw = reference.pso_draws
+    monkeypatch.setattr(reference, "pso_draws", lambda raw_key, cfg: draw(
+        raw_key, dict(cfg, dtype="float32")))
+
+
+def _run(name, cell_fault=None, monkeypatch=None):
+    cell = _tiny.tiny_cell(name)
+    if cell_fault is not None:
+        cell_fault(cell, monkeypatch)
+    return harness.run(cell, SEED, 0.5, False, time.perf_counter(),
+                       require_kernel=False, log=lambda m: None)
 
 
 @pytest.fixture(autouse=True)
@@ -118,11 +140,19 @@ def test_sound_run_is_correct(name):
 
 CAUGHT = {"half": "count_gap", "skip_half": "sweep_gap",
           "early_stop": "stop_gap", "altered": "best_gap"}
+PER_LANE = {"skip_half", "early_stop"}  # faults a per-lane cell cannot have
 
 
-@pytest.mark.parametrize("fault", [frozen, half, skip_half, early_stop, altered],
-                         ids=lambda f: f.__name__)
-@pytest.mark.parametrize("name", sorted(_tiny.SIZES))
+def _faults():
+    for name in sorted(_tiny.SIZES):
+        per_lane = work.sweep_mode(_tiny.tiny_cell(name)["cfg"]) == "per_lane"
+        for fault in (frozen, half, skip_half, early_stop, altered):
+            if not (per_lane and fault.__name__ in PER_LANE):
+                yield pytest.param(name, fault,
+                                   id=f"{name}-{fault.__name__}")
+
+
+@pytest.mark.parametrize("name,fault", list(_faults()))
 def test_broken_solve_is_not_correct(name, fault, monkeypatch):
     fault(monkeypatch)
     out = _run(name)
@@ -132,3 +162,24 @@ def test_broken_solve_is_not_correct(name, fault, monkeypatch):
     else:
         c = out["checks"][CAUGHT[fault.__name__]]
         assert c["value"] > c["limit"], out["checks"]
+
+
+FLOAT64 = sorted(n for n in _tiny.SIZES
+                 if _tiny.tiny_cell(n)["cfg"]["dtype"] == "float64")
+
+
+@pytest.mark.parametrize("fault", [f32_build, f32_replay],
+                         ids=lambda f: f.__name__)
+@pytest.mark.parametrize("name", FLOAT64)
+def test_float64_cell_refuses_float32(name, fault, monkeypatch):
+    """A float32 build of a float64 cell fails its limits (its gradient and
+    values are off by float32's rounding, its swarm by float32's draws), and
+    so does a float64 program checked against a float32 swarm."""
+    out = _run(name, fault, monkeypatch)
+    assert not out["correct"] and out["failed"] == 0, out["checks"]
+    over = {k for k in reference.NAMES
+            if out["checks"][k]["value"] > out["checks"][k]["limit"]}
+    # float32 rounds the swarm's best and the finale's value alike; a swarm
+    # drawn apart moves the swarm's best
+    assert over & ({"pso_gap", "best_gap"} if fault is f32_build
+                   else {"pso_gap"}), out["checks"]

@@ -1,4 +1,4 @@
-"""Every cell of BENCHMARK.json loads by name and builds its configuration
+"""Every cell of BENCHMARK.json, and each held cell, loads by name and builds its configuration
 and traffic from a seed; the file keeps to its contract; the measured path
 refuses a CPU device."""
 import json
@@ -8,6 +8,7 @@ import subprocess
 import sys
 
 import _paths  # noqa: F401
+import _tiny
 import numpy as np
 import pytest
 
@@ -22,9 +23,9 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SEED = 2**31 + 11  # run seeds may pass 32 signed bits
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS + sorted(_tiny.HELD))
 def test_cell_builds_from_its_files(name):
-    cell = spec.load_cell(name)
+    cell = _tiny.held_cell(name) if name in _tiny.HELD else spec.load_cell(name)
     cfg = cell["cfg"]
     opts = harness.zeus_options(cfg)
     assert opts.pso.n_particles == cfg["zeus"]["pso"]["n_particles"]

@@ -13,7 +13,7 @@ import scopes
 import spec
 import trace_reduce as tr
 
-READERS = ("phase1_ms", "ladder_ms", "update_stage_ms")
+READERS = ("phase1_ms", "ladder_ms", "update_stage_ms", "fused_sweep_ms")
 BODY = base64.b64encode(b"\x01_guarded_update_direction_kernel\x00").decode()
 TEXT = "\n".join([
     '%fused_computation (param_0: f32[8]) -> f32[8] {',

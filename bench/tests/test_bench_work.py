@@ -21,13 +21,13 @@ def test_rastrigin_d10_lane_sweep_by_hand():
     cfg, prob = _problem("rastrigin-d10")
     # update: Hy, y.Hy, three rank-one terms, p = -H'g -> 10*D^2 + 6*D flops;
     # H read + written and s, y, g, p -> (2*D^2 + 4*D) floats
-    assert work.update_work(10) == (1060, 960)
+    assert work.update_work(10, 4) == (1060, 960)
     # value: 6 flops/coord, x read + f written; value+grad: 11 flops/coord,
     # x read, f and g written
     assert prob.row_work(cfg) == {"value": (60, 44), "value_grad": (110, 84)}
     # trial x + a*p: 20 flops, x and p read (the value row's own x read is
     # the trial) -> 20 + 60 + 110 + 1060 flops, 80 - 40 + 44 + 84 + 960 bytes
-    assert work.lane_sweep_work(10, prob.row_work(cfg)) == (1250, 1128)
+    assert work.lane_sweep_work(10, prob.row_work(cfg), 4) == (1250, 1128)
 
 
 def test_lane_sweeps_decode_the_counter():
@@ -42,6 +42,16 @@ def test_solve_work_sums_lanes():
     flops, nbytes = work.solve_work(cfg, prob, np.array([2, 24, 68]))
     # 4 lane-sweeps and 3 initial value+grad rows
     assert (flops, nbytes) == (4 * 1250 + 3 * 110, 4 * 1128 + 3 * 84)
+
+
+def test_solve_work_at_the_configurations_dtype_and_path():
+    cfg, prob = _problem("rastrigin-d10")
+    f64 = dict(cfg, dtype="float64")
+    flops, nbytes = work.solve_work(f64, prob, np.array([2, 24, 68]))
+    assert (flops, nbytes) == (4 * 1250 + 3 * 110, 2 * (4 * 1128 + 3 * 84))
+    # a per-lane sweep's counter bounds its sweeps: no required work counted
+    per_lane = dict(cfg, zeus=dict(cfg["zeus"], sweep_mode="per_lane"))
+    assert work.solve_work(per_lane, prob, np.array([2, 24, 68])) is None
 
 
 def test_least_time_takes_the_larger_bound():
