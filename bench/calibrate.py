@@ -11,7 +11,7 @@ below the one the configuration states: for float64, the program's own
 float32 path (the configuration with `dtype` float32, compiled apart, on
 the same keys and data, in a window of its own); for float32, the
 reference in bfloat16 in the program's place. One JSON line per seed: each
-number as a run reads it over the solves it compares (reference.aggregate).
+number as a run reads it over the toys it compares (reference.aggregate).
 The lower reading of a limit is the largest the program gives over a dozen
 seeds or more; the upper, the smallest the control gives.
 """
@@ -96,10 +96,10 @@ def _seed(cell, problem, exe, seed, seconds, control, require_kernel):
             jax.block_until_ready(exe(*stream.warmup_args(j)))
     t0 = time.perf_counter()
     win = harness.drive(exe, stream, seconds, harness.no_annotation)
-    answers = harness.collect(win)
-    idx = harness.sample(stream, win)
-    line = {"seed": seed, "solves": len(answers), "compared": len(idx),
-            "window_s": win.elapsed}
+    answers = harness.collect(win, stream.toys)
+    idx = harness.sample(stream, win, answers)
+    line = {"seed": seed, "solves": len(win.latencies), "toys": len(answers),
+            "compared": len(idx), "window_s": win.elapsed}
     for tag in ("program", "control") if control else ("program",):
         per_solve, failed = harness.check(answers, idx, stream, cfg, problem,
                                           log, control=tag == "control")

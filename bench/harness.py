@@ -41,9 +41,11 @@ def zeus_options(cfg: dict):
 
 
 def solve_program(cfg: dict, problem):
-    """`(key data[, dataset]) -> ZeusResult` through zeus_jit."""
+    """`(key data[, dataset]) -> ZeusResult` through zeus_jit; where the
+    configuration sets `toys_per_solve`, zeus under jax.vmap over the toys'
+    stacked keys and datasets, as a user batches many fits in one call."""
     import jax
-    from repro.core.zeus import zeus_jit
+    from repro.core.zeus import zeus, zeus_jit
 
     opts = zeus_options(cfg)
     dim, lo, hi = cfg["dim"], float(cfg["lower"]), float(cfg["upper"])
@@ -52,7 +54,11 @@ def solve_program(cfg: dict, problem):
         f = problem.program_objective(cfg, data)
         return zeus_jit(f, dim, lo, hi, opts)(jax.random.wrap_key_data(raw_key))
 
-    return solve
+    def toy(raw_key, data=None):
+        return zeus(problem.program_objective(cfg, data),
+                    jax.random.wrap_key_data(raw_key), dim, lo, hi, opts)
+
+    return jax.vmap(toy) if cfg.get("toys_per_solve") else solve
 
 
 def precision(cfg: dict):
@@ -255,12 +261,13 @@ def _run(cell, seed, seconds, trace, t_start, require_kernel, trace_dir, log):
     log(f"[window] {n} solves in {win.elapsed:.3f}s; setup {setup_s:.3f}s")
 
     peak = memory_peak(exe, log)
-    answers = collect(win)
-    idx = sample(stream, win)
-    per_solve, failed = check(answers, idx, stream, cfg, problem, log)
-    failed += sum(1 for i, a in enumerate(answers)
-                  if i not in idx and not answered(a))
-    ok, checks = reference.judge(per_solve, cfg["limits"])
+    answers = collect(win, stream.toys)
+    idx = sample(stream, win, answers)
+    per_toy, failed = check(answers, idx, stream, cfg, problem, log)
+    compared = set(idx)
+    failed += sum(1 for j, a in enumerate(answers)
+                  if j not in compared and not answered(a))
+    ok, checks = reference.judge(per_toy, cfg["limits"])
 
     e2e = {"solve_s": win.elapsed / n, "setup_s": setup_s}
     device = device_info(peak)
@@ -277,7 +284,7 @@ def _run(cell, seed, seconds, trace, t_start, require_kernel, trace_dir, log):
     else:
         for m in cell["end_to_end"]:
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
-    out = {"correct": bool(ok and failed == 0), "attempted": n,
+    out = {"correct": bool(ok and failed == 0), "attempted": len(answers),
            "failed": failed, "metrics": metrics, "device": device}
     if summary is not None:
         out["breakdown"] = summary.breakdown
@@ -285,17 +292,34 @@ def _run(cell, seed, seconds, trace, t_start, require_kernel, trace_dir, log):
     return out
 
 
-def collect(win: Window) -> list:
-    """Every answer on the host, and the program's state freed."""
-    answers = [None if o is None else answer_of(o) for o in win.outputs]
+def split_toys(a: dict, toys) -> list:
+    """One solve's answer as the answers of its toys: with `toys` None the
+    answer itself, else each toy's slice along the leading axis."""
+    if toys is None:
+        return [a]
+    return [{k: v[t] for k, v in a.items()} for t in range(toys)]
+
+
+def collect(win: Window, toys=None) -> list:
+    """Every toy's answer on the host (None for each toy of a solve that
+    raised), and the program's state freed."""
+    answers = []
+    for o in win.outputs:
+        answers += ([None] * (toys or 1) if o is None
+                    else split_toys(answer_of(o), toys))
     win.outputs = None
     return answers
 
 
-def sample(stream: Stream, win: Window) -> list:
-    """The solves compared: a sample drawn from the seed, and the slowest."""
-    n = len(win.latencies)
-    return sorted(set(stream.sample(n)) | {int(np.argmax(win.latencies))})
+def sample(stream: Stream, win: Window, answers: list) -> list:
+    """The toys compared: a sample drawn from the seed, and in the slowest
+    solve the toy that ran the most sweeps."""
+    per = stream.per_solve
+    first = per * int(np.argmax(win.latencies))
+    sweeps = [-1 if a is None else int(a["iterations"])
+              for a in answers[first:first + per]]
+    slowest = first + int(np.argmax(sweeps))
+    return sorted(set(stream.sample(len(answers))) | {slowest})
 
 
 def answered(a) -> bool:
@@ -304,16 +328,17 @@ def answered(a) -> bool:
 
 
 def check(answers, idx, stream, cfg, problem, log, control=False):
-    """Readings of the solves `idx` against the reference (with `control`,
-    of the bfloat16 control in the program's place); (readings, failed)."""
-    per_solve, failed = [], 0
-    for i in idx:
-        a = answers[i]
+    """Readings of the toys `idx` against the reference, each with its own
+    key, data and stop rule (with `control`, of the bfloat16 control in the
+    program's place); (readings, failed)."""
+    per_toy, failed = [], 0
+    for j in idx:
+        a = answers[j]
         if a is None:
             failed += 1
             continue
-        data = stream.data_of(i)
-        draws = reference.pso_draws(stream.args(i)[0], cfg)
+        data = stream.data_of(j)
+        draws = reference.pso_draws(stream.key_of(j), cfg)
         pso_ref = reference.replay_pso(
             draws, lambda z: problem.value(z, data, cfg), cfg)
         if control:
@@ -321,17 +346,18 @@ def check(answers, idx, stream, cfg, problem, log, control=False):
         r, why = reference.readings(a, problem, cfg, data, pso_ref)
         if why:
             failed += 1
-            log(f"[check] solve {i} failed: {why}")
+            log(f"[check] toy {j} failed: {why}")
         else:
-            per_solve.append(r)
-    return per_solve, failed
+            per_toy.append(r)
+    return per_toy, failed
 
 
 @dataclasses.dataclass
 class Context:
     """What a per-layer metric reader may read: the configuration, the
-    answers of the traced solves on the host, the trace's Summary, the
-    problem module and the chip's kind."""
+    answers of the traced solves' toys on the host (in order, the toys of
+    a solve that raised left out), the trace's Summary, the problem module
+    and the chip's kind."""
     cfg: dict
     trace: object
     problem: object

@@ -1,7 +1,8 @@
 """The plain reference, the comparison that decides `correct`, and its control.
 
-Nothing here imports the program. A solve's answer is judged by what it
-says, layer by layer, over every lane:
+Nothing here imports the program. A toy's answer (one problem's: a solve
+carries one toy, or many under jax.vmap) is judged by what it says, layer by
+layer, over every lane:
 
 - phase 1: the paper's PSO (w, c1, c2 update, personal and global best by
   strict improvement, first index on ties) is replayed in float64 from the
@@ -24,7 +25,7 @@ says, layer by layer, over every lane:
 - finale: `best_f` must be the float64 value at `best_x`, and that the least
   float64 value over the converged lanes (`best_gap`).
 
-A run reads `pso_gap` as the median over the solves it compares, the other
+A run reads `pso_gap` as the median over the toys it compares, the other
 numbers as their worst (`AGGREGATE`).
 
 The control of a float32 configuration puts this reference in the

@@ -72,6 +72,8 @@ def program_text(cfg: dict, problem) -> str:
         data = problem.make_data(cfg, np.random.default_rng(0))
         if data is not None:
             args += (data,)
+        if cfg.get("toys_per_solve"):  # the solve's inputs stacked over toys
+            args = tuple(np.stack([a] * cfg["toys_per_solve"]) for a in args)
         t0 = time.perf_counter()
         with _no_persistent_cache(), harness.precision(cfg):
             _, _texts[key] = harness.compile_solve(

@@ -11,7 +11,13 @@ underneath, it comes out not correct, once for each fault a cell can have.
   best lane);
 - f32_build (float64 cells): the same configuration built in float32;
 - f32_replay (float64 cells): the reference's swarm drawn in float32
-  against the float64 program's.
+  against the float64 program's;
+- cells whose solve carries many toys: shifted_toys (each toy's answer
+  handed to its neighbour, so it is judged against another toy's key and
+  counts), raised_sweeps (in each solve the toy with the fewest sweeps
+  reports more sweeps than it ran: the batch's most, or more where its
+  lanes' eval counters admit that many), neighbour_pso (each toy reports
+  its neighbour's swarm best).
 
 A per-lane cell has no skip_half (its step is vmapped over single lanes,
 so no step sees a batch to halve) and no early_stop (the dijet fit stops
@@ -26,10 +32,12 @@ import time
 import _tiny
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import harness
 import reference
+import spec
 import work
 
 SEED = 2**31 + 3
@@ -108,6 +116,39 @@ def altered(monkeypatch):
                         lambda res: (lambda x, f: (x + 0.05, f))(*select(res)))
 
 
+def _toys_altered(monkeypatch, alter):
+    split = harness.split_toys
+    monkeypatch.setattr(harness, "split_toys",
+                        lambda a, toys: alter(split(a, toys)))
+
+
+def shifted_toys(monkeypatch, cfg):
+    _toys_altered(monkeypatch, lambda toys: toys[1:] + toys[:1])
+
+
+def raised_sweeps(monkeypatch, cfg):
+    vg = spec.problem_module(cfg).vg_cost(cfg)
+
+    def raised(toys):
+        sweeps = [int(t["iterations"]) for t in toys]
+        toy = toys[int(np.argmin(sweeps))]
+        # a per-lane sweep tries 1 to 20 rungs, so a lane's counter admits
+        # a range of sweeps: the raise is past what every lane still
+        # active admits, at least to the batch's most
+        most = work.sweep_range(cfg, toy["n_evals"], vg)[1]
+        live = toy["status"] == reference.STOPPED
+        toy["iterations"] = np.int32(max(max(sweeps), most[live].min() + 1))
+        return toys
+
+    _toys_altered(monkeypatch, raised)
+
+
+def neighbour_pso(monkeypatch, cfg):
+    _toys_altered(monkeypatch, lambda toys: [
+        dict(t, pso_best_f=toys[(i + 1) % len(toys)]["pso_best_f"])
+        for i, t in enumerate(toys)])
+
+
 def f32_build(cell, monkeypatch):
     cell["cfg"]["dtype"] = "float32"
 
@@ -139,22 +180,32 @@ def test_sound_run_is_correct(name):
 
 
 CAUGHT = {"half": "count_gap", "skip_half": "sweep_gap",
-          "early_stop": "stop_gap", "altered": "best_gap"}
+          "early_stop": "stop_gap", "altered": "best_gap",
+          "shifted_toys": "fval_gap", "raised_sweeps": "stop_gap",
+          "neighbour_pso": "pso_gap"}
 PER_LANE = {"skip_half", "early_stop"}  # faults a per-lane cell cannot have
+TOYS = {"shifted_toys", "raised_sweeps", "neighbour_pso"}  # many toys only
 
 
 def _faults():
     for name in sorted(_tiny.SIZES):
-        per_lane = work.sweep_mode(_tiny.tiny_cell(name)["cfg"]) == "per_lane"
-        for fault in (frozen, half, skip_half, early_stop, altered):
-            if not (per_lane and fault.__name__ in PER_LANE):
-                yield pytest.param(name, fault,
-                                   id=f"{name}-{fault.__name__}")
+        cfg = _tiny.tiny_cell(name)["cfg"]
+        per_lane = work.sweep_mode(cfg) == "per_lane"
+        for fault in (frozen, half, skip_half, early_stop, altered,
+                      shifted_toys, raised_sweeps, neighbour_pso):
+            if per_lane and fault.__name__ in PER_LANE:
+                continue
+            if "toys_per_solve" not in cfg and fault.__name__ in TOYS:
+                continue
+            yield pytest.param(name, fault, id=f"{name}-{fault.__name__}")
 
 
 @pytest.mark.parametrize("name,fault", list(_faults()))
 def test_broken_solve_is_not_correct(name, fault, monkeypatch):
-    fault(monkeypatch)
+    if fault.__name__ in TOYS:
+        fault(monkeypatch, _tiny.tiny_cell(name)["cfg"])
+    else:
+        fault(monkeypatch)
     out = _run(name)
     assert not out["correct"], out["checks"]
     if fault is frozen:  # no lane converges: every solve fails

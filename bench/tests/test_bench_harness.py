@@ -30,12 +30,17 @@ def test_cell_builds_from_its_files(name):
     opts = harness.zeus_options(cfg)
     assert opts.pso.n_particles == cfg["zeus"]["pso"]["n_particles"]
     assert opts.bfgs.theta == cfg["zeus"]["bfgs"]["theta"]
+    assert cell["mix"]["pool"] % cfg.get("toys_per_solve", 1) == 0
     problem = spec.problem_module(cfg)
-    stream = Stream(dict(cell["mix"], pool=4), cfg, problem, SEED)
+    toys = cfg.get("toys_per_solve")
+    stream = Stream(dict(cell["mix"], pool=4 * (toys or 1)), cfg, problem,
+                    SEED)
+    lead = (toys,) if toys else ()
     for i in range(3):
         args = stream.args(i)
-        assert args[0].shape == (2,) and args[0].dtype == np.uint32
+        assert args[0].shape == lead + (2,) and args[0].dtype == np.uint32
         assert len(args) == 1 + bool(cell["mix"]["data"])
+        assert all(a.shape[:len(lead)] == lead for a in args)
     assert not np.array_equal(stream.args(0)[0], stream.args(1)[0])
     assert set(cfg["limits"]) == set(harness.reference.NAMES)
     for m in cell["per_layer"]:

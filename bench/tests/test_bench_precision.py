@@ -57,8 +57,8 @@ def test_replay_draws_the_programs_swarm_at_init(name):
     cfg = _tiny.tiny_cell(name)["cfg"]
     cfg["zeus"]["pso"]["n_particles"] = 64
     problem = spec.problem_module(cfg)
-    raw = Stream({"pool": 1, "check_sample": 1, "data": False}, cfg,
-                 problem, SEED).args(0)[0]
+    raw = Stream({"pool": 4, "check_sample": 1, "data": False}, cfg,
+                 problem, SEED).key_of(0)
     with harness.precision(cfg):
         x, v = reference.pso_draws(raw, cfg)[:2]
         swarm = init_swarm(lambda z: z.sum(), jax.random.wrap_key_data(raw),
